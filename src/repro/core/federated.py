@@ -36,6 +36,10 @@ def fedavg(adapter_trees: Sequence[Any], weights: Optional[Sequence[float]]
         w = w / w.sum()
 
     def avg(*leaves):
+        if isinstance(leaves[0], jax.Array):
+            # members may serve from different devices (one replica per
+            # chip): average on the first member's device
+            leaves = jax.device_put(leaves, leaves[0].sharding)
         out = leaves[0] * w[0]
         for wi, leaf in zip(w[1:], leaves[1:]):
             out = out + wi * leaf

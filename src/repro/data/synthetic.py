@@ -10,6 +10,7 @@ quality metric (1/CE) needs to show continuous adaptation.
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from typing import Dict, Iterator, Optional, Tuple
 
 import numpy as np
@@ -27,8 +28,10 @@ class SyntheticDataset:
     branching: int = 7   # candidate next-tokens per token (lower=easier)
 
     def __post_init__(self):
+        # crc32, not hash(): str hashes are salted per process, and the
+        # same seed must give the same corpus in every run
         rng = np.random.default_rng(
-            abs(hash((self.domain, self.seed))) % (2 ** 31))
+            zlib.crc32(f"{self.domain}:{self.seed}".encode()))
         v, k = self.vocab_size, self.branching
         self.next_tokens = rng.integers(0, v, size=(v, k))
         self.next_probs = rng.dirichlet(np.ones(k) * 0.6, size=v)

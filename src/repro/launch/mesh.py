@@ -22,7 +22,7 @@ import re
 from typing import Any, Optional, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import Family, ModelConfig, ShapeCell
 from repro.models.sharding import (
@@ -30,21 +30,11 @@ from repro.models.sharding import (
 )
 
 
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    """jax.make_mesh across jax versions: older releases have neither
-    the ``axis_types`` kwarg nor ``jax.sharding.AxisType`` (Auto is
-    their only behavior), newer ones default to Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def rules_for(cfg: ModelConfig, mesh: Mesh, kind: str,

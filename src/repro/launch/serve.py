@@ -30,6 +30,8 @@ per request from ``--seed`` so runs are reproducible.
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b \
       --requests 16 --prompt-len 32 --gen 16
+  ... --full         # the architecture's published widths (bf16)
+                     # instead of the 2-layer smoke reduction
   ... --combined     # fine-tune while serving (one XLA program)
   ... --paged --block-size 16 --n-blocks 64   # paged KV cache (block
                      # tables; memory scales with live tokens)
@@ -65,6 +67,7 @@ import numpy as np
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core.engine import make_engine
 from repro.data.synthetic import SyntheticDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.serving_loop import ContinuousBatcher, GenRequest
 
 
@@ -166,6 +169,7 @@ def run_serving(arch: str, *, smoke: bool = True, n_requests: int = 16,
     per_req = [r.finished_at for r in requests
                if r.finished_at is not None]
     out = {
+        "completed": stats.finished,
         "tokens_generated": stats.generated_tokens,
         "prefill_tokens": stats.prefill_tokens,
         "decode_steps": stats.decode_steps,
@@ -173,6 +177,7 @@ def run_serving(arch: str, *, smoke: bool = True, n_requests: int = 16,
         "throughput_tok_s": stats.throughput(),
         "train_losses": batcher.train_losses,
         "cache_bytes": batcher.cache_bytes(),
+        "outputs": [list(r.tokens) for r in requests],
     }
     if paged:
         out["peak_used_blocks"] = batcher.allocator.peak_used
@@ -257,6 +262,7 @@ def run_multi_replica_serving(
     out = fabric.run(requests)
     out["completed"] = sum(1 for r in requests
                            if r.completed_at is not None)
+    out["outputs"] = [list(r.output_tokens or []) for r in requests]
     if verbose:
         c = out["cluster"]
         print(f"fabric served {out['completed']}/{n_requests} requests "
@@ -330,6 +336,7 @@ def run_combined_fabric_serving(
     out = fabric.run(requests, min_rounds=rounds, timeout=timeout)
     out["completed"] = sum(1 for r in requests
                            if r.completed_at is not None)
+    out["outputs"] = [list(r.output_tokens or []) for r in requests]
     if verbose:
         c = out["cluster"]
         print(f"combined fabric served {out['completed']}/{n_requests} "
@@ -359,6 +366,9 @@ def run_combined_fabric_serving(
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="qwen1.5-0.5b")
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="serve the architecture at its published widths "
+                         "(default: the reduced smoke config)")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -433,6 +443,7 @@ def main() -> None:
                     help="NaN-poisoned train rounds to schedule "
                          "(combined mode)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.prefix_cache and not args.paged:
         ap.error("--prefix-cache requires --paged (sharing rides on "
                  "pool block aliasing)")
@@ -453,7 +464,7 @@ def main() -> None:
             # the full co-execution path: launcher-driven incremental
             # train sessions over the live fabric
             run_combined_fabric_serving(
-                args.arch, n_replicas=args.replicas,
+                args.arch, n_replicas=args.replicas, smoke=args.smoke,
                 n_requests=args.requests, prompt_len=args.prompt_len,
                 gen_tokens=args.gen, batch_size=args.batch,
                 paged=args.paged, block_size=args.block_size,
@@ -468,7 +479,7 @@ def main() -> None:
                 seed=args.seed, chaos=chaos)
             return
         run_multi_replica_serving(
-            args.arch, n_replicas=args.replicas,
+            args.arch, n_replicas=args.replicas, smoke=args.smoke,
             n_requests=args.requests, prompt_len=args.prompt_len,
             gen_tokens=args.gen, batch_size=args.batch,
             paged=args.paged, block_size=args.block_size,
@@ -480,7 +491,7 @@ def main() -> None:
             oversubscribe=args.oversubscribe, swap=args.swap,
             seed=args.seed, chaos=chaos)
         return
-    run_serving(args.arch, n_requests=args.requests,
+    run_serving(args.arch, smoke=args.smoke, n_requests=args.requests,
                 prompt_len=args.prompt_len, gen_tokens=args.gen,
                 batch_size=args.batch, combined=args.combined,
                 train_batch=args.train_batch,

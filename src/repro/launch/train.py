@@ -25,6 +25,7 @@ from repro.checkpoint import Checkpointer
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core.engine import make_engine
 from repro.data.synthetic import SyntheticDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.grad_noise import NoiseScaleEMA
 
 
@@ -126,6 +127,7 @@ def main() -> None:
     ap.add_argument("--restore", action="store_true")
     ap.add_argument("--lr", type=float, default=3e-3)
     args = ap.parse_args()
+    enable_compile_cache()
     out = run_training(args.arch, smoke=args.smoke, steps=args.steps,
                        batch=args.batch, seq=args.seq,
                        ckpt_dir=args.ckpt, restore=args.restore,

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.sharding import shard, shard_map_compat
+from repro.models.sharding import shard
 
 
 # ---------------------------------------------------------------- norms ----
@@ -455,8 +455,8 @@ def attention_decode_seqsharded(q, k_new, v_new, k_cache, v_cache, pos, *,
 
     pq = P(batch_ax, None, None, None)
     pc = P(batch_ax, seq_ax, None, None)
-    out, new_k, new_v = shard_map_compat(
-        body, mesh=mesh,
+    out, new_k, new_v = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(pq, pq, pq, pc, pc, P()),
         out_specs=(pq, pc, pc),
     )(q, k_new, v_new, k_cache, v_cache, pos)

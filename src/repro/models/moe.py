@@ -20,7 +20,7 @@ from jax import lax
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import dense_init
-from repro.models.sharding import shard, shard_map_compat
+from repro.models.sharding import shard
 
 
 class MoEParams(NamedTuple):
@@ -157,8 +157,8 @@ def moe_decode_shardmap(params: MoEParams, x: jax.Array, cfg: ModelConfig
 
     pw_g = P(e_ax, d_ax, f_ax)
     pw_d = P(e_ax, f_ax, d_ax)
-    y, aux = shard_map_compat(
-        body, mesh=mesh,
+    y, aux = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P(), P(), pw_g, pw_g, pw_d),
         out_specs=(P(), P()),
     )(xt, params.router, params.wg, params.wu, params.wd)

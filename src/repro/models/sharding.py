@@ -22,19 +22,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 Axis = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: newer releases expose it as
-    ``jax.shard_map`` (replication check kwarg ``check_vma``), older
-    ones as ``jax.experimental.shard_map.shard_map`` (``check_rep``)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class ShardingRules:
     """logical axis name -> mesh axis (or tuple of axes, or None)."""

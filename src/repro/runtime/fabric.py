@@ -30,13 +30,17 @@ The fabric owns the wall-clock control loop:
 
 ``build_fabric`` is the one-call constructor used by
 ``launch/serve.py --replicas N`` and ``benchmarks/multi_replica.py``:
-every replica shares the same frozen base params (the paper's
+every replica serves the same frozen base params (the paper's
 model-sharing premise) but owns its adapter, optimizer state, and KV
-cache pool.
+cache pool.  Replica ``i`` lives on ``jax.devices()[i % n_devices]``:
+replicas on one device share one copy of the base params, replicas on
+distinct chips each hold their own.  The fabric still pumps them one
+after another from one host loop.
 """
 from __future__ import annotations
 
 import dataclasses
+import logging
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -49,6 +53,8 @@ from repro.runtime.fault import (
 )
 from repro.runtime.metrics import aggregate_serve_stats
 from repro.runtime.replica import LiveReplica
+
+log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -226,15 +232,21 @@ class ServingFabric:
             try:
                 served = rep.pump_once(now)
             except Exception as e:          # noqa: BLE001 — containment
+                log.warning("replica %s: pump failed, failing over", rid,
+                            exc_info=True)
                 self.health.failure(rid, now,
                                     reason=type(e).__name__)
                 continue
             # heartbeat off REAL pump progress; serving ticks feed
-            # their wall latency to the straggler watch (idle ticks
-            # are ~free and would drag the medians toward zero)
+            # their wall latency to the straggler watch.  Idle ticks
+            # are ~free and would drag the medians toward zero; ticks
+            # with a fused train leg cost what the session's batch
+            # costs, so peers outside a session (or a replica leaving
+            # one a tick before its peer) are not comparable to them
+            compare = served and not rep.batcher.last_tick_trained
             self.health.beat(rid, now,
                              busy_s=time.perf_counter() - t0
-                             if served else None)
+                             if compare else None)
             busy = served or busy
         dead, stragglers = self.health.poll(now)
         for rid in dead:
@@ -342,6 +354,8 @@ class ServingFabric:
                   "overload_promotions": d.overload_promotions}
             for sid, d in self.cluster.dispatchers.items()}
         launcher = self.cluster.launcher
+        out["devices"] = {rid: sorted(d.id for d in rep.devices())
+                          for rid, rep in self.replicas.items()}
         out["fl_rounds"] = launcher.completed_rounds
         out["rounds"] = [dict(r) for r in launcher.round_history]
         out["adapter_versions"] = dict(launcher.adapter_versions)
@@ -397,9 +411,10 @@ def build_fabric(arch: str, n_replicas: int, *, smoke: bool = True,
                  cfg: Optional[FabricConfig] = None,
                  injector: Optional[FaultInjector] = None,
                  ) -> Tuple[ServingFabric, Any]:
-    """Build a fabric of ``n_replicas`` live replicas over ONE shared
-    set of frozen base params (each replica owns its adapter, optimizer
-    state, and cache pool).  Returns ``(fabric, model_cfg)``.
+    """Build a fabric of ``n_replicas`` live replicas over ONE set of
+    frozen base params (each replica owns its adapter, optimizer state,
+    and cache pool).  Replica ``i`` is placed on
+    ``jax.devices()[i % n_devices]``.  Returns ``(fabric, model_cfg)``.
 
     ``train_pool > 0`` fixes the fine-tuning corpus to that many
     batches cycled epoch-style (a finite finetuning set, the realistic
@@ -466,7 +481,9 @@ def build_fabric(arch: str, n_replicas: int, *, smoke: bool = True,
         # prep to measured serving wall time
         make_data_fn()(fabric.cfg.train_batch)
     fabric.injector = injector
+    devices = jax.devices()
     for i in range(n_replicas):
+        device = devices[i % len(devices)]
         if n_adapters > 0:
             # tenant0's no-op tree doubles as the replica's co-training
             # adapter — identical on every replica, so mixed placement
@@ -480,7 +497,7 @@ def build_fabric(arch: str, n_replicas: int, *, smoke: bool = True,
         if n_adapters > 0:
             from repro.runtime.serving_loop import AdapterRegistry
             registry = AdapterRegistry(
-                model, capacity=adapter_slots or n_adapters)
+                model, capacity=adapter_slots or n_adapters, device=device)
             for t, tree in enumerate(tenant_trees):
                 registry.register(f"tenant{t}", tree)
             train_tenant = "tenant0"
@@ -495,5 +512,5 @@ def build_fabric(arch: str, n_replicas: int, *, smoke: bool = True,
             serve_prefill_chunk=fabric.cfg.prefill_chunk,
             serve_tpot_target=fabric.cfg.tpot_target,
             serve_oversubscribe=fabric.cfg.oversubscribe,
-            serve_swap=fabric.cfg.swap))
+            serve_swap=fabric.cfg.swap, device=device))
     return fabric, mcfg

@@ -404,12 +404,12 @@ class LiveReplica:
                  serve_prefill_chunk: int = 0,
                  serve_tpot_target: float = 0.0,
                  serve_oversubscribe: float = 0.0,
-                 serve_swap: bool = True):
+                 serve_swap: bool = True,
+                 device: Any = None):
         from repro.runtime.serving_loop import ContinuousBatcher
         self.replica_id = replica_id
         self.model_id = model_id
         self.engine = engine
-        self.params = params
         self.on_result = on_result
         self.data_fn = data_fn          # batch_size -> training batch dict
         self.eval_fn = eval_fn          # lora -> eval CE loss
@@ -456,7 +456,10 @@ class LiveReplica:
             n_blocks=serve_n_blocks, prefix_cache=serve_prefix_cache,
             adapters=adapters, prefill_chunk=serve_prefill_chunk,
             tpot_target=serve_tpot_target,
-            oversubscribe=serve_oversubscribe, swap=serve_swap)
+            oversubscribe=serve_oversubscribe, swap=serve_swap,
+            device=device)
+        # the batcher's copy: committed to this replica's device
+        self.params = self.batcher.params
         from repro.runtime.serving_loop import _engine_jits
         self._jit_loss = _engine_jits(engine)["loss"]
 
@@ -796,7 +799,9 @@ class LiveReplica:
             return
         if self._session is not None:
             self.abort_round(0.0)
-        self.lora = adapter
+        from repro.runtime.serving_loop import place
+        # a merged global may arrive from another replica's device
+        self.lora = place(adapter, self.batcher.device)
         self.adapter_version = version
         self.batcher.train_lora = None
         self.batcher.stats.adapter_version = version
@@ -804,6 +809,17 @@ class LiveReplica:
 
     def get_adapter(self) -> Any:
         return self.lora
+
+    def devices(self) -> set:
+        """Every device holding this replica's params, adapter,
+        optimizer state, KV pool or tenant adapter slots."""
+        import jax
+        b = self.batcher
+        trees = [b.params, b.lora, b.opt_state, b.caches]
+        if self.adapters is not None:
+            trees.append(self.adapters.device_lora())
+        return {d for leaf in jax.tree_util.tree_leaves(trees)
+                if isinstance(leaf, jax.Array) for d in leaf.devices()}
 
     # ------------------------------------------- incremental sessions ------
     def begin_round(self, train_batch: int, infer_batch: int, steps: int,

@@ -101,6 +101,13 @@ from repro.runtime.paging import BlockAllocator, PrefixCache, blocks_for
 from repro.runtime.sanitize import adapter_sanitizer, lifecycle_sanitizer
 
 
+def place(tree: Any, device: Optional[Any]) -> Any:
+    """Commit every array of ``tree`` to ``device``; ``None`` leaves
+    the tree where it is (JAX's default device, uncommitted).  A tree
+    already on ``device`` is not copied."""
+    return tree if device is None else jax.device_put(tree, device)
+
+
 @functools.lru_cache(maxsize=16)
 def _engine_jits(engine) -> Dict[str, Callable]:
     """One set of jitted step programs per (frozen, hashable) Engine —
@@ -438,14 +445,18 @@ class AdapterRegistry:
     segmented kernel, whose concatenated B contraction touches every
     slot's columns (masked rows contribute exact zeros, not NaN)."""
 
-    def __init__(self, model, capacity: int):
+    def __init__(self, model, capacity: int, device: Optional[Any] = None):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
         self.capacity = capacity
+        # the stacked slots live on the serving replica's device; host
+        # trees registered from elsewhere (failover) move there on load
+        self.device = device
         specs = model.lora_specs()
-        self._stack = jax.tree.map(
-            lambda s: jnp.zeros((s.shape[0], capacity) + s.shape[1:],
-                                s.dtype), specs)
+        with jax.default_device(device):
+            self._stack = place(jax.tree.map(
+                lambda s: jnp.zeros((s.shape[0], capacity) + s.shape[1:],
+                                    s.dtype), specs), device)
         self._host: Dict[str, Any] = {}
         self._version: Dict[str, int] = {}
         self._slot: Dict[str, int] = {}        # resident tenants only
@@ -545,7 +556,7 @@ class AdapterRegistry:
                 f"{adapter_id}: all {self.capacity} adapter slots are "
                 "pinned by in-flight requests")
         self._stack = _write_adapter_slot(
-            self._stack, self._host[adapter_id],
+            self._stack, place(self._host[adapter_id], self.device),
             jnp.asarray(slot, jnp.int32))
         self.loads += 1
         self._slot[adapter_id] = slot
@@ -588,7 +599,8 @@ class AdapterRegistry:
         slot = self._slot.get(adapter_id)
         if slot is not None:
             self._stack = _write_adapter_slot(
-                self._stack, tree, jnp.asarray(slot, jnp.int32))
+                self._stack, place(tree, self.device),
+                jnp.asarray(slot, jnp.int32))
         if self.san is not None:
             self.san.end_publish(adapter_id, version)
 
@@ -637,7 +649,8 @@ class ContinuousBatcher:
                  attn_backend: Optional[str] = None,
                  adapters: Optional[AdapterRegistry] = None,
                  prefill_chunk: int = 0, tpot_target: float = 0.0,
-                 oversubscribe: float = 0.0, swap: bool = True):
+                 oversubscribe: float = 0.0, swap: bool = True,
+                 device: Optional[Any] = None):
         cfg = engine.model.cfg
         if n_slots < 1:
             # run() makes progress only through slots; zero would spin
@@ -667,9 +680,13 @@ class ContinuousBatcher:
         self.engine = engine
         self.model = engine.model
         self.cfg = cfg
-        self.params = params
-        self.lora = lora
-        self.opt_state = opt_state
+        # replica placement: params, adapter, optimizer state and KV
+        # pool all live on ``device`` (None = JAX's default device), so
+        # every step program of this batcher runs there
+        self.device = device
+        self.params = place(params, device)
+        self.lora = place(lora, device)
+        self.opt_state = place(opt_state, device)
         self.adapters = adapters
         self.n_slots = n_slots
         self.max_seq = max_seq
@@ -719,8 +736,9 @@ class ContinuousBatcher:
                         "and would break cache-on/off greedy identity")
             self.prefix_cache = PrefixCache(self.allocator) \
                 if prefix_cache else None
-            self.caches = self.model.init_paged_caches(n_blocks,
-                                                       block_size)
+            with jax.default_device(device):
+                self.caches = place(self.model.init_paged_caches(
+                    n_blocks, block_size), device)
             # all-zero rows park inactive slots on scratch block 0
             self.block_tables = np.zeros((n_slots, self.blocks_per_slot),
                                          np.int32)
@@ -739,7 +757,9 @@ class ContinuousBatcher:
                     "prefix_cache requires paged=True (sharing rides "
                     "on pool block aliasing)")
             self.prefix_cache = None
-            self.caches = self.model.init_caches(n_slots, max_seq)
+            with jax.default_device(device):
+                self.caches = place(
+                    self.model.init_caches(n_slots, max_seq), device)
         # --------------------------------------- oversubscribed pool --
         # oversubscribe = w (0 < w <= 1): admission reserves only
         # near-term need against a w-fraction watermark of the pool;
@@ -1873,7 +1893,7 @@ class ContinuousBatcher:
                     # slot's garbage decode write corrupt a real block
                     tbl = tbl.copy()
                     tbl[pref, :] = 0
-                self._dev_tables = jnp.asarray(tbl)
+                self._dev_tables = jax.device_put(tbl, self.device)
                 self._dev_tables_width = width
             tables = self._dev_tables
         if self._lsan is not None:

@@ -7,7 +7,6 @@ import numpy as np
 import pytest
 
 from repro.checkpoint import Checkpointer
-from repro.launch.mesh import make_mesh_compat
 
 
 def _tree():
@@ -67,13 +66,13 @@ def test_shape_mismatch_rejected(tmp_path):
 
 def test_elastic_restore_mesh_change(tmp_path):
     """Restore under a different mesh/shardings (elastic restart)."""
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.runtime.elastic import elastic_restore
     ck = Checkpointer(str(tmp_path))
     tree = {"w": jnp.arange(16.0).reshape(4, 4)}
     ck.save(1, tree)
     ck.wait()
-    mesh = make_mesh_compat((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     restored, _ = elastic_restore(ck, jax.eval_shape(lambda: tree), mesh,
                                   lambda key, leaf: P())
     np.testing.assert_array_equal(np.asarray(restored["w"]),

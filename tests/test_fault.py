@@ -345,6 +345,39 @@ def test_straggler_quarantine_requeues_and_recovers():
         assert r.output_tokens == ref, f"req {i} diverged"
 
 
+def test_train_ticks_do_not_flag_stragglers():
+    """A tick with a fused train leg costs what the session's batch
+    costs, not what the replica's health does: a replica whose train
+    ticks are slow (here r0, by 0.05 s each) is no straggler."""
+    from repro.runtime.fabric import FabricConfig, build_fabric
+
+    cfg_f = FabricConfig(enable_finetuning=True, bootstrap_steps=3,
+                         steps_per_round=3, decision_interval=0.05,
+                         straggler_threshold=2.0, straggler_window=8,
+                         straggler_min_samples=2, straggler_warmup=0,
+                         health_poll_interval=0.0)
+    fab, cfg = build_fabric(ARCH, 2, n_slots=SLOTS,
+                            prompt_len=PROMPT_PAD, gen_tokens=MAX_GEN,
+                            paged=True, block_size=4, cfg=cfg_f)
+    batcher = fab.replicas["r0"].batcher
+    step = batcher.step
+
+    def slow_train_step(train_batch=None, **kw):
+        out = step(train_batch=train_batch, **kw)
+        if batcher.last_tick_trained:
+            time.sleep(0.05)
+        return out
+
+    batcher.step = slow_train_step
+    lens = [6, 8, 5, 7, 6, 9, 4, 8]
+    gens = [6, 6, 5, 6, 4, 5, 6, 5]
+    reqs, _ = _fabric_requests(cfg, lens, gens)
+    out = fab.run(reqs, min_rounds=1, timeout=120.0)
+    assert out["fl_rounds"] >= 1
+    assert all(r.completed_at is not None for r in reqs)
+    assert fab.quarantines == 0, fab.fault_log
+
+
 def test_retry_budget_exhaustion_terminal_status():
     """With a zero retry budget, requests drained from a crashed
     replica are terminally rejected — the run loop settles instead of

@@ -3,11 +3,11 @@ filtering, duplicate-axis dedup, per-arch coverage."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.launch.mesh import (
-    batch_shardings, logical_axes_for, make_mesh_compat, param_shardings,
+    batch_shardings, logical_axes_for, param_shardings,
     rules_for,
 )
 from repro.models.model import build
@@ -17,11 +17,12 @@ from repro.models.sharding import (
 
 
 def _mesh11():
-    return make_mesh_compat((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def test_filter_spec_drops_nondivisible():
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = _mesh11()
     # craft a fake mesh shape dict via a real mesh of size 1 but checking
     # logic with the mesh axis sizes it reports
     spec = _filter_spec(P("model", "data"), mesh, (25, 16))
@@ -63,7 +64,7 @@ def test_param_shardings_cover_every_leaf(arch):
 
 
 def test_rules_for_head_fallback():
-    mesh16 = make_mesh_compat((1, 1), ("data", "model"))
+    mesh16 = _mesh11()
     # qwen3 has 40 heads: on a 16-way model axis they don't divide —
     # emulate by checking the rule function's branch directly
 

@@ -15,7 +15,7 @@ import numpy as np
 from repro.configs.registry import get_config
 from repro.models.model import build
 from repro.models.sharding import ShardingRules, sharding_context
-from repro.launch.mesh import make_mesh_compat, rules_for
+from jax.sharding import AxisType
 
 cfg = get_config("llama3-8b").scaled(n_layers=2, d_model=64, n_heads=4,
                                      d_ff=128, vocab_size=256)
@@ -34,7 +34,8 @@ for t in range(S):
     ref.append(lg)
 
 # sharded: 2x4 mesh, kv_seq on "model" (4-way) -> shard_map path
-mesh = make_mesh_compat((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rules = dataclasses.replace(
     ShardingRules(), kv_seq="model", kv_batch="data")
 with sharding_context(mesh, rules):

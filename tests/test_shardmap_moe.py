@@ -14,7 +14,7 @@ import numpy as np
 from repro.configs.base import Family, ModelConfig
 from repro.models.moe import MoEParams, init_moe, moe_mlp
 from repro.models.sharding import ShardingRules, sharding_context
-from repro.launch.mesh import make_mesh_compat
+from jax.sharding import AxisType
 
 for moe_shard, rules_kw in [
     ("ep", dict(experts="model", expert_ff=None, w_embed="data")),
@@ -29,7 +29,8 @@ for moe_shard, rules_kw in [
     x = jax.random.normal(jax.random.key(1), (4, 8, 32), jnp.float32)
     y_ref, aux_ref = moe_mlp(p, x, cfg)   # no mesh -> plain path
 
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     rules = dataclasses.replace(ShardingRules(), **rules_kw)
     with sharding_context(mesh, rules):
         y_sm, aux_sm = jax.jit(lambda pp, xx: moe_mlp(pp, xx, cfg))(p, x)
