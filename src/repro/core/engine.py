@@ -62,34 +62,36 @@ class Engine:
                 skip_masked_blocks=skip_masked_blocks)
             return loss, metrics
 
-        if grad_accum <= 1:
-            (loss, metrics), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(lora, batch)
-            micro_sqnorm = global_norm(grads) ** 2
-        else:
-            micro = jax.tree.map(
-                lambda x: x.reshape((grad_accum, x.shape[0] // grad_accum)
-                                    + x.shape[1:]), batch)
+        with jax.named_scope("train_fwd_bwd"):
+            if grad_accum <= 1:
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(lora, batch)
+                micro_sqnorm = global_norm(grads) ** 2
+            else:
+                micro = jax.tree.map(
+                    lambda x: x.reshape((grad_accum, x.shape[0] // grad_accum)
+                                        + x.shape[1:]), batch)
 
-            def body(carry, mb):
-                g_acc, l_acc, sq_acc = carry
-                (loss, _), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(lora, mb)
-                g_acc = jax.tree.map(
-                    lambda a, g: a + g.astype(jnp.float32) / grad_accum,
-                    g_acc, grads)
-                sq = global_norm(grads) ** 2
-                return (g_acc, l_acc + loss / grad_accum,
-                        sq_acc + sq / grad_accum), None
+                def body(carry, mb):
+                    g_acc, l_acc, sq_acc = carry
+                    (loss, _), grads = jax.value_and_grad(
+                        loss_fn, has_aux=True)(lora, mb)
+                    g_acc = jax.tree.map(
+                        lambda a, g: a + g.astype(jnp.float32) / grad_accum,
+                        g_acc, grads)
+                    sq = global_norm(grads) ** 2
+                    return (g_acc, l_acc + loss / grad_accum,
+                            sq_acc + sq / grad_accum), None
 
-            zeros = jax.tree.map(
-                lambda x: jnp.zeros(x.shape, jnp.float32), lora)
-            (grads, loss, micro_sqnorm), _ = jax.lax.scan(
-                body, (zeros, jnp.float32(0.0), jnp.float32(0.0)), micro)
-            metrics = {"ce_loss": loss}
+                zeros = jax.tree.map(
+                    lambda x: jnp.zeros(x.shape, jnp.float32), lora)
+                (grads, loss, micro_sqnorm), _ = jax.lax.scan(
+                    body, (zeros, jnp.float32(0.0), jnp.float32(0.0)), micro)
+                metrics = {"ce_loss": loss}
 
-        new_lora, new_opt, opt_metrics = self.optimizer.update(
-            grads, opt_state, lora)
+        with jax.named_scope("optimizer"):
+            new_lora, new_opt, opt_metrics = self.optimizer.update(
+                grads, opt_state, lora)
         metrics = dict(metrics)
         metrics.update(opt_metrics)
         metrics["loss"] = loss
@@ -139,10 +141,11 @@ class Engine:
         multi-tenant tree (leaves [L, A, din, r]) with per-row slot
         selection — the AdapterRegistry decode wave.
         """
-        logits, new_caches = self.model.decode_step(
-            params, lora if serve_lora is None else serve_lora,
-            caches, token, pos, attn_backend=attn_backend,
-            adapter_idx=serve_adapter_idx)
+        with jax.named_scope("serve"):
+            logits, new_caches = self.model.decode_step(
+                params, lora if serve_lora is None else serve_lora,
+                caches, token, pos, attn_backend=attn_backend,
+                adapter_idx=serve_adapter_idx)
         new_lora, new_opt, metrics = self.train_step(
             params, lora, opt_state, train_batch, grad_accum=grad_accum,
             train_tokens=train_tokens)
@@ -164,11 +167,12 @@ class Engine:
         block-table decode tick fused into one program (same pre-update
         snapshot semantics, ``serve_lora`` shadow split, and
         ``serve_adapter_idx`` multi-tenant row selection)."""
-        logits, new_caches = self.model.decode_step_paged(
-            params, lora if serve_lora is None else serve_lora,
-            caches, token, pos, block_tables,
-            ring_len=ring_len, attn_backend=attn_backend,
-            adapter_idx=serve_adapter_idx)
+        with jax.named_scope("serve"):
+            logits, new_caches = self.model.decode_step_paged(
+                params, lora if serve_lora is None else serve_lora,
+                caches, token, pos, block_tables,
+                ring_len=ring_len, attn_backend=attn_backend,
+                adapter_idx=serve_adapter_idx)
         new_lora, new_opt, metrics = self.train_step(
             params, lora, opt_state, train_batch, grad_accum=grad_accum,
             train_tokens=train_tokens)

@@ -21,6 +21,9 @@ class Request:
     tokens: int = 128           # output length (token-level goodput §8.1)
     dispatched: bool = False
     dispatch_time: Optional[float] = None   # when a subflow picked it up
+    # live replicas: end of the pump tick whose host pull brought the
+    # first generated token back (same clock as ``arrival``)
+    first_token_at: Optional[float] = None
     completed_at: Optional[float] = None
     quality: float = 0.0        # response quality when served (1/CE)
     # live serving: concrete prompt token ids ([P] int32).  None on the

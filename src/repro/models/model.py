@@ -66,6 +66,12 @@ def chunked_ce_loss(hidden: jax.Array, head: jax.Array, labels: jax.Array,
     return loss, {"loss_sum": tot, "token_count": cnt}
 
 
+@jax.named_scope("lm_head")
+def _lm_head(hidden: jax.Array, params: Dict) -> jax.Array:
+    """Vocabulary logits of ``hidden`` (a scope of its own in a trace)."""
+    return hidden @ params["lm_head"]
+
+
 def _scan_or_loop(body, init, xs):
     """Unrolled drop-in for lax.scan over stacked-leading-dim xs trees.
     Used by cost-calibration compiles (scan_layers=False): XLA's
@@ -218,7 +224,7 @@ class Model:
     def logits(self, params, lora, batch):
         """Full-vocab logits for the whole sequence (smoke-scale only)."""
         hidden, _, _ = self.hidden_states(params, lora, batch)
-        return hidden @ params["lm_head"]
+        return _lm_head(hidden, params)
 
     # ------------------------------------------------------------- caches --
     def init_caches(self, batch: int, seq: int, dtype=None) -> Dict:
@@ -275,7 +281,7 @@ class Model:
         hidden, caches, _ = self.hidden_states(
             params, lora, batch, collect_caches=True, block_kv=block_kv,
             skip_masked_blocks=skip_masked_blocks)
-        logits = hidden[:, -1:] @ params["lm_head"]
+        logits = _lm_head(hidden[:, -1:], params)
         out_caches: Dict[str, Any] = {}
         if caches and caches.get("kv") is not None:
             out_caches["kv"] = caches["kv"]
@@ -310,7 +316,7 @@ class Model:
         last = jnp.take_along_axis(
             hidden, jnp.broadcast_to(idx, (hidden.shape[0], 1,
                                            hidden.shape[2])), axis=1)
-        logits = last @ params["lm_head"]
+        logits = _lm_head(last, params)
         out_caches: Dict[str, Any] = {}
         if caches and caches.get("kv") is not None:
             out_caches["kv"] = caches["kv"]
@@ -451,7 +457,7 @@ class Model:
         last = jnp.take_along_axis(
             hidden, jnp.broadcast_to(idx, (hidden.shape[0], 1,
                                            hidden.shape[2])), axis=1)
-        logits = last @ params["lm_head"]
+        logits = _lm_head(last, params)
         return logits, {"kv": kvs}
 
     def prefill_ragged_continue(self, params, lora, batch, suffix_lens,
@@ -505,7 +511,7 @@ class Model:
         last = jnp.take_along_axis(
             hidden, jnp.broadcast_to(idx, (hidden.shape[0], 1,
                                            hidden.shape[2])), axis=1)
-        logits = last @ params["lm_head"]
+        logits = _lm_head(last, params)
         return logits, {"kv": kvs}
 
     def write_prefill_rows(self, pool_caches, prefill_caches, slots,
@@ -640,7 +646,7 @@ class Model:
             x, new_caches = scan(body, x,
                                  (params["blocks"], lora, cache_tree))
         hidden = rms_norm(x, params["final_norm"])
-        logits = hidden @ params["lm_head"]
+        logits = _lm_head(hidden, params)
         return logits, new_caches
 
     def decode_step_paged(self, params, lora, caches, token, pos,
@@ -690,7 +696,7 @@ class Model:
 
         x, new_kv = scan(body, x, (params["blocks"], lora, caches["kv"]))
         hidden = rms_norm(x, params["final_norm"])
-        logits = hidden @ params["lm_head"]
+        logits = _lm_head(hidden, params)
         return logits, {"kv": new_kv}
 
     # ---------------------------------------------------------- input specs -

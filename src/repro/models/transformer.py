@@ -94,6 +94,11 @@ def init_cross_block(key, cfg: ModelConfig) -> Dict:
 
 
 # ------------------------------------------------------------- attention ---
+# The layer bodies name their phases with ``jax.named_scope`` —
+# ``attn_qkv``, ``kv_write``, ``attn``, ``attn_out``, ``mlp`` (and
+# ``lm_head`` in models/model.py) — so a device trace's ops say which
+# phase of a layer they belong to.  Scopes are op metadata only.
+@jax.named_scope("attn_qkv")
 def _proj_qkv(p, x, cfg, lora, adapter_idx=None):
     sc = cfg.lora.scaling
     q = lora_lib.apply(x, x @ p["wq"], lora.get("q") if lora else None, sc,
@@ -112,6 +117,14 @@ def _proj_qkv(p, x, cfg, lora, adapter_idx=None):
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
     return q, k, v
+
+
+@jax.named_scope("attn_out")
+def _proj_out(p, o, cfg, lora, adapter_idx=None):
+    """Output projection (with LoRA) of attention's [B, S, H, Dh]."""
+    o = o.reshape(o.shape[0], o.shape[1], cfg.n_heads * cfg.head_dim)
+    return lora_lib.apply(o, o @ p["wo"], lora.get("o") if lora else None,
+                          cfg.lora.scaling, adapter_idx)
 
 
 def use_dense_prefill(cfg: ModelConfig, s: int) -> bool:
@@ -140,20 +153,18 @@ def attn_full(p, x, cfg: ModelConfig, rope_cs, lora=None,
     v = shard(v, "batch", "seq", "kv_heads", None)
     causal = not cfg.encoder_only
     s = x.shape[1]
-    if use_dense_prefill(cfg, s):
-        o = attention_dense(q, k, v, causal=causal,
-                            window=cfg.sliding_window)
-    else:
-        o = attention_blockwise(q, k, v, causal=causal,
-                                window=cfg.sliding_window,
-                                block_kv=block_kv,
-                                skip_masked_blocks=skip_masked_blocks
-                                and causal,
-                                unroll=cfg.unroll_attn_blocks)
-    o = o.reshape(x.shape[0], s, cfg.n_heads * cfg.head_dim)
-    out = lora_lib.apply(o, o @ p["wo"], lora.get("o") if lora else None,
-                         cfg.lora.scaling, adapter_idx)
-    return out, (k, v)
+    with jax.named_scope("attn"):
+        if use_dense_prefill(cfg, s):
+            o = attention_dense(q, k, v, causal=causal,
+                                window=cfg.sliding_window)
+        else:
+            o = attention_blockwise(q, k, v, causal=causal,
+                                    window=cfg.sliding_window,
+                                    block_kv=block_kv,
+                                    skip_masked_blocks=skip_masked_blocks
+                                    and causal,
+                                    unroll=cfg.unroll_attn_blocks)
+    return _proj_out(p, o, cfg, lora, adapter_idx), (k, v)
 
 
 def attn_decode(p, x, cfg: ModelConfig, cache_kv, pos, rope_cs, lora=None,
@@ -189,33 +200,29 @@ def attn_decode(p, x, cfg: ModelConfig, cache_kv, pos, rope_cs, lora=None,
         and cache_len % mesh.shape[rules.kv_seq] == 0)
     if use_sharded:
         from repro.models.layers import attention_decode_seqsharded
-        o, k_cache, v_cache = attention_decode_seqsharded(
-            q, k, v, k_cache, v_cache, pos)
-        o = o.reshape(x.shape[0], 1, cfg.n_heads * cfg.head_dim)
-        out = lora_lib.apply(o, o @ p["wo"],
-                             lora.get("o") if lora else None,
-                             cfg.lora.scaling, adapter_idx)
-        return out, (k_cache, v_cache)
+        with jax.named_scope("attn"):
+            o, k_cache, v_cache = attention_decode_seqsharded(
+                q, k, v, k_cache, v_cache, pos)
+        return _proj_out(p, o, cfg, lora, adapter_idx), (k_cache, v_cache)
 
     wpos = lax.rem(pos, cache_len) if cfg.sliding_window > 0 else pos
-    if ragged:
-        # each sequence writes its new K/V at its own cache position
-        row_update = jax.vmap(
-            lambda c, new, w: lax.dynamic_update_slice_in_dim(
-                c, new, w, axis=0))
-        k_cache = row_update(k_cache, k.astype(k_cache.dtype), wpos)
-        v_cache = row_update(v_cache, v.astype(v_cache.dtype), wpos)
-    else:
-        k_cache = lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), wpos, axis=1)
-        v_cache = lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), wpos, axis=1)
+    with jax.named_scope("kv_write"):
+        if ragged:
+            # each sequence writes its new K/V at its own cache position
+            row_update = jax.vmap(
+                lambda c, new, w: lax.dynamic_update_slice_in_dim(
+                    c, new, w, axis=0))
+            k_cache = row_update(k_cache, k.astype(k_cache.dtype), wpos)
+            v_cache = row_update(v_cache, v.astype(v_cache.dtype), wpos)
+        else:
+            k_cache = lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), wpos, axis=1)
+            v_cache = lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), wpos, axis=1)
     kv_len = jnp.minimum(pos + 1, cache_len)
-    o = attention_decode(q, k_cache, v_cache, kv_len, backend=backend)
-    o = o.reshape(x.shape[0], 1, cfg.n_heads * cfg.head_dim)
-    out = lora_lib.apply(o, o @ p["wo"], lora.get("o") if lora else None,
-                         cfg.lora.scaling, adapter_idx)
-    return out, (k_cache, v_cache)
+    with jax.named_scope("attn"):
+        o = attention_decode(q, k_cache, v_cache, kv_len, backend=backend)
+    return _proj_out(p, o, cfg, lora, adapter_idx), (k_cache, v_cache)
 
 
 def attn_decode_paged(p, x, cfg: ModelConfig, pool_kv, rope_cs,
@@ -238,16 +245,15 @@ def attn_decode_paged(p, x, cfg: ModelConfig, pool_kv, rope_cs,
     # distinct sequences own distinct blocks, so indices never collide
     # (inactive slots share scratch block 0, where last-write-wins
     # garbage is fine: their logits are discarded)
-    k_pool = k_pool.at[write_block, write_off].set(
-        k[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[write_block, write_off].set(
-        v[:, 0].astype(v_pool.dtype))
-    o = attention_decode_paged(q, k_pool, v_pool, block_tables, kv_len,
-                               backend=backend)
-    o = o.reshape(x.shape[0], 1, cfg.n_heads * cfg.head_dim)
-    out = lora_lib.apply(o, o @ p["wo"], lora.get("o") if lora else None,
-                         cfg.lora.scaling, adapter_idx)
-    return out, (k_pool, v_pool)
+    with jax.named_scope("kv_write"):
+        k_pool = k_pool.at[write_block, write_off].set(
+            k[:, 0].astype(k_pool.dtype))
+        v_pool = v_pool.at[write_block, write_off].set(
+            v[:, 0].astype(v_pool.dtype))
+    with jax.named_scope("attn"):
+        o = attention_decode_paged(q, k_pool, v_pool, block_tables,
+                                   kv_len, backend=backend)
+    return _proj_out(p, o, cfg, lora, adapter_idx), (k_pool, v_pool)
 
 
 def attn_prefill_suffix(p, x, cfg: ModelConfig, prefix_kv, prefix_len,
@@ -263,12 +269,10 @@ def attn_prefill_suffix(p, x, cfg: ModelConfig, prefix_kv, prefix_len,
         cos, sin = rope_cs
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
     k_pre, v_pre = prefix_kv
-    o = attention_prefix_suffix(q, k_pre, v_pre, k, v, prefix_len,
-                                window=cfg.sliding_window)
-    o = o.reshape(x.shape[0], x.shape[1], cfg.n_heads * cfg.head_dim)
-    out = lora_lib.apply(o, o @ p["wo"], lora.get("o") if lora else None,
-                         cfg.lora.scaling, adapter_idx)
-    return out, (k, v)
+    with jax.named_scope("attn"):
+        o = attention_prefix_suffix(q, k_pre, v_pre, k, v, prefix_len,
+                                    window=cfg.sliding_window)
+    return _proj_out(p, o, cfg, lora, adapter_idx), (k, v)
 
 
 def block_prefill_suffix(bp, x, cfg: ModelConfig, prefix_kv, prefix_len,
@@ -308,6 +312,7 @@ def vision_kv(p, vis: jax.Array, cfg: ModelConfig):
 
 
 # ----------------------------------------------------------------- blocks --
+@jax.named_scope("mlp")
 def _mlp_out(bp, h, cfg, lora, adapter_idx=None):
     if "moe" in bp:
         from repro.models.moe import MoEParams, moe_mlp

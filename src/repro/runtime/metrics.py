@@ -2,7 +2,7 @@
 
 goodput   — output tokens/s of responses that met their SLO deadline
 Q-goodput — goodput weighted by response quality (= 1 / CE loss)
-plus utilization timelines and control-plane overhead accounting.
+plus utilization timelines.
 """
 from __future__ import annotations
 
@@ -23,14 +23,10 @@ class MetricsCollector:
         self.results: List[BatchResult] = []
         self.util_samples: Dict[str, List[Tuple[float, float]]] = \
             collections.defaultdict(list)
-        self.overhead_time: float = 0.0
-        self.infer_time: float = 0.0
-        self.train_time: float = 0.0
 
     # ------------------------------------------------------------- inputs --
     def on_result(self, result: BatchResult, stream_id: str) -> None:
         self.results.append(result)
-        self.infer_time += result.infer_latency
 
     def sample_utilization(self, replica_id: str, now: float,
                            util: float) -> None:
@@ -76,10 +72,6 @@ class MetricsCollector:
         sums = np.bincount(idx, weights=us, minlength=nb)
         cnts = np.maximum(np.bincount(idx, minlength=nb), 1)
         return (np.arange(nb) + 0.5) * bucket, sums / cnts
-
-    def overhead_fraction(self) -> float:
-        total = self.overhead_time + self.infer_time + self.train_time
-        return self.overhead_time / max(total, 1e-9)
 
 
 # =========================================================================

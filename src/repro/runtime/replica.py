@@ -424,6 +424,9 @@ class LiveReplica:
         # [Request], {gen_id: GenRequest}, ingest wall stamp)
         self._inflight: List[Tuple[float, float, List[Request],
                                    Dict[int, Any], float]] = []
+        # ingested requests whose first token has not reached the host
+        # yet: ``_stamp_first_tokens`` stamps ``Request.first_token_at``
+        self._awaiting_first: List[Tuple[Request, Any]] = []
         self._gen_counter = 0
         self._busy_frac = 0.0
         self._last_loss = float("nan")
@@ -515,7 +518,7 @@ class LiveReplica:
                 g = GenRequest(
                     request_id=self._gen_counter, prompt=prompt,
                     max_new_tokens=min(r.tokens, self.max_gen_tokens),
-                    arrival=now, adapter_id=r.adapter_id,
+                    arrival=r.arrival, adapter_id=r.adapter_id,
                     deadline=r.deadline,
                     temperature=r.temperature,
                     top_k=r.top_k, top_p=r.top_p,
@@ -526,6 +529,7 @@ class LiveReplica:
                 self._gen_counter += 1
                 self.batcher.submit(g)
                 group[g.request_id] = g
+                self._awaiting_first.append((r, g))
             self._inflight.append((submit_t, submit_wall, batch, group,
                                    _time.perf_counter()))
 
@@ -562,12 +566,27 @@ class LiveReplica:
                 train_batch=self.train_batch), batch[0].stream_id)
         self._inflight = still
 
+    def _stamp_first_tokens(self) -> None:
+        """Hand the control plane the first-token stamp (the caller's
+        ``now`` of the pump tick whose host pull brought it back) of
+        every request whose first token arrived in the tick that just
+        ran."""
+        if self._awaiting_first:
+            waiting = []
+            for r, g in self._awaiting_first:
+                if g.first_token_at is not None:
+                    r.first_token_at = g.first_token_at
+                else:
+                    waiting.append((r, g))
+            self._awaiting_first = waiting
+
     def pump(self, now: float) -> None:
         """Synchronously drain queued serving work through the
         continuous batcher (examples drive this)."""
         self._ingest(now)
         while not self.batcher.idle():
             self.batcher.step(now=now)
+            self._stamp_first_tokens()
             self._emit_finished(now)
             self._ingest(now)
 
@@ -606,6 +625,7 @@ class LiveReplica:
             t0 = _time.perf_counter()
             self.batcher.step(train_batch=tb, now=now)
             dt = _time.perf_counter() - t0
+            self._stamp_first_tokens()
             if serving:
                 # per-replica busy time: this replica's share of the
                 # device (per-replica throughput = its tokens / its
@@ -773,13 +793,16 @@ class LiveReplica:
                 tokens=tokens, train_batch=self.train_batch),
                 batch[0].stream_id)
         self._inflight.clear()
+        self._awaiting_first.clear()
         for _s, _w, batch in self._queue:
             out.extend(batch)
         self._queue.clear()
         self.batcher.drain_all()
         self._busy_frac = 0.0
         for r in out:
-            r.completed_at = None
+            # partial generations are discarded: a survivor stamps the
+            # first token it produces
+            r.completed_at = r.first_token_at = None
         return out
 
     # ------------------------------------------------------------ training -

@@ -100,6 +100,12 @@ from repro.core.interfaces import slack_order
 from repro.runtime.paging import BlockAllocator, PrefixCache, blocks_for
 from repro.runtime.sanitize import adapter_sanitizer, lifecycle_sanitizer
 
+# Every host span of the serving tick opens through this one name: a
+# profiler annotation (``collm.*``) that lands in the same trace as the
+# device ops, on their clock, and costs about a microsecond with the
+# profiler off.  Tests swap it for a recorder.
+_span = jax.profiler.TraceAnnotation
+
 
 def place(tree: Any, device: Optional[Any]) -> Any:
     """Commit every array of ``tree`` to ``device``; ``None`` leaves
@@ -175,13 +181,19 @@ class GenRequest:
     seed: Optional[int] = None
     # filled by the runtime
     tokens: List[int] = dataclasses.field(default_factory=list)
-    prefill_at: Optional[float] = None
-    # when the FIRST generated token landed — equals ``prefill_at`` on
-    # monolithic prefill, later under chunking (the TTFT stamp)
+    # the caller's ``now`` of the tick whose host pull brought the
+    # FIRST generated token back (``LiveReplica`` hands it to the
+    # control plane's ``Request.first_token_at``)
     first_token_at: Optional[float] = None
     finished_at: Optional[float] = None
-    # wall-clock (perf_counter) finish stamp — ``finished_at`` carries
-    # whatever clock the caller's ``now`` uses, which may be sim time
+    # wall-clock (perf_counter) stamps where the work happens — the
+    # ``*_at`` stamps carry whatever clock the caller's ``now`` uses,
+    # which may be sim time or the start of the tick: ``submit()``,
+    # the admission that gave it a slot, the host pull that brought
+    # its first token back, and its finish
+    submitted_wall: Optional[float] = None
+    admitted_wall: Optional[float] = None
+    first_token_wall: Optional[float] = None
     finished_wall: Optional[float] = None
     rng: Any = None                     # per-request sampling stream
 
@@ -271,9 +283,10 @@ class ServeStats:
     swap_out_blocks: int = 0
     swap_in_blocks: int = 0
     reprefill_tokens: int = 0
-    # per-finished-request latency samples (caller's ``now`` clock):
-    # time to first token and seconds per subsequent output token —
-    # aggregate_serve_stats folds these into p50/p99
+    # per-finished-request latency samples (perf_counter stamps): time
+    # to first token (submit -> first token on the host) and seconds
+    # per subsequent output token — aggregate_serve_stats folds these
+    # into p50/p99
     ttft: List[float] = dataclasses.field(default_factory=list)
     tpot: List[float] = dataclasses.field(default_factory=list)
 
@@ -918,6 +931,7 @@ class ContinuousBatcher:
         req.max_new_tokens = max(1, min(req.max_new_tokens, budget))
         if self._lsan is not None:
             self._lsan.on_submit(req)
+        req.submitted_wall = time.perf_counter()
         self.queue.append(req)
 
     def active_slots(self) -> List[int]:
@@ -994,13 +1008,12 @@ class ContinuousBatcher:
         req.finished_at = now
         req.finished_wall = time.perf_counter()
         self.stats.finished += 1
-        first = req.first_token_at if req.first_token_at is not None \
-            else req.prefill_at
-        if first is not None:
-            self.stats.ttft.append(max(first - req.arrival, 0.0))
+        first = req.first_token_wall
+        if first is not None and req.submitted_wall is not None:
+            self.stats.ttft.append(first - req.submitted_wall)
             if len(req.tokens) > 1:
                 self.stats.tpot.append(
-                    max(now - first, 0.0) / (len(req.tokens) - 1))
+                    (req.finished_wall - first) / (len(req.tokens) - 1))
         if req.adapter_id is not None:
             self.stats.adapter_requests[req.adapter_id] = \
                 self.stats.adapter_requests.get(req.adapter_id, 0) + 1
@@ -1157,6 +1170,7 @@ class ContinuousBatcher:
                 # pin the tenant's device slot for the request lifetime
                 # (loads from host on a miss; can_acquire gated above)
                 self.adapters.acquire(head.adapter_id)
+            head.admitted_wall = time.perf_counter()
             reqs.append(head)
             picked.append(idx)
             idx += 1
@@ -1170,6 +1184,17 @@ class ContinuousBatcher:
             # tick's token budget
             self._assign_chunked(free, reqs, plans, now)
             return finished
+        with _span("collm.prefill_wave", rows=len(reqs),
+                   tokens=sum(len(r.prompt) for r in reqs),
+                   ids=" ".join(str(r.request_id) for r in reqs)):
+            return self._admit_wave(free, reqs, plans, now)
+
+    def _admit_wave(self, free: List[int], reqs: List[GenRequest],
+                    plans: List, now: float) -> List[GenRequest]:
+        """Prefill the requests ``admit`` picked, stamp their first
+        tokens, bind them to ``free`` slots and land the wave's K/V in
+        the cache; returns the requests done at admission."""
+        finished: List[GenRequest] = []
         firsts, entries, last_logits = self._prefill_wave(
             reqs, plans if self.paged else None)
         # one batched scatter per wave on the ragged-attention paths;
@@ -1202,7 +1227,7 @@ class ContinuousBatcher:
             n_cached = len(matched) * (self.block_size if self.paged
                                        else 0)
             req.tokens.append(first)
-            req.prefill_at = now
+            req.first_token_wall = time.perf_counter()
             req.first_token_at = now
             self.stats.admitted += 1
             self.stats.prefill_tokens += len(req.prompt) - n_cached
@@ -1276,7 +1301,6 @@ class ContinuousBatcher:
             matched, reserved = plans[k] if self.paged else ([], 0)
             n_cached = len(matched) * (self.block_size if self.paged
                                        else 0)
-            req.prefill_at = now
             self.stats.admitted += 1
             self.stats.cached_prefix_tokens += n_cached
             self.slot_req[slot] = req
@@ -1417,6 +1441,7 @@ class ContinuousBatcher:
                     host_rows[j], temperature=req.temperature,
                     top_k=req.top_k, top_p=req.top_p, rng=req.rng)
             req.tokens.append(first)
+            req.first_token_wall = time.perf_counter()
             req.first_token_at = now
             self.stats.generated_tokens += 1
             if self.paged:
@@ -1815,29 +1840,46 @@ class ContinuousBatcher:
         microbatch (full / halved / skipped) into whatever budget
         remains.  Without chunking/budget knobs this reduces to the
         original admit + full-wave tick.  Returns the requests that
-        finished this tick."""
+        finished this tick.
+
+        The tick and each of its phases run under a ``collm.*`` host
+        span (``_span``); ``collm.step`` carries the decode wave's live
+        rows and whether the tick trained."""
         if train_batch is not None and self.opt_state is None:
             raise ValueError(
                 "step(train_batch=...) requires opt_state (pass it to "
                 "the ContinuousBatcher constructor)")
+        with _span("collm.step") as span:
+            finished, rows = self._tick(train_batch, now)
+            span.set_metadata(rows=rows,
+                              trained=int(self.last_tick_trained))
+        return finished
+
+    def _tick(self, train_batch: Optional[Dict[str, Any]], now: float):
+        """``step``'s body; returns (finished requests, decode rows)."""
         budget = self.budget
         self.last_tick_trained = False
         self.last_tick_train_rows = 0
         if self._swapped:
-            self._restore(now)
-        finished = self.admit(now)
+            with _span("collm.restore"):
+                self._restore(now)
+        with _span("collm.admit"):
+            finished = self.admit(now)
         prefill_spent = 0.0
         chunked = self.prefill_chunk > 0 or self.oversubscribe > 0
         if chunked and self.prefilling_slots():
             allowance = float("inf") if budget is None else \
                 budget.prefill_allowance(len(self.decoding_slots()))
-            done, prefill_spent = self._advance_prefill(now, allowance)
+            with _span("collm.advance_prefill"):
+                done, prefill_spent = self._advance_prefill(now,
+                                                            allowance)
             finished.extend(done)
         active = self.decoding_slots() if chunked \
             else self.active_slots()
         if self.oversubscribe > 0 and active:
             # preempt-or-reserve BEFORE _grow_tables takes fresh blocks
-            active = self._ensure_headroom(active, now)
+            with _span("collm.headroom"):
+                active = self._ensure_headroom(active, now)
         if not active:
             if train_batch is not None:
                 ref = train_batch.get("tokens",
@@ -1852,7 +1894,8 @@ class ContinuousBatcher:
                     self.stats.train_skipped_ticks += 1
                 else:
                     t0 = time.perf_counter()
-                    self._plain_train(train_batch, train_tokens=tt)
+                    with _span("collm.train"):
+                        self._plain_train(train_batch, train_tokens=tt)
                     rows = b if tt == 0 else max(1, min(b, tt // s))
                     if budget is not None:
                         budget.observe_train(
@@ -1860,7 +1903,103 @@ class ContinuousBatcher:
                     self.last_tick_trained = True
                     self.last_tick_train_rows = rows
             self._record_budget(prefill_spent)
-            return finished
+            return finished, 0
+        tables = None
+        if self.paged:
+            with _span("collm.grow_tables"):
+                tables = self._wave_tables(active)
+        if self._lsan is not None:
+            self._sanitize_wave(active)
+        # budget the tick's leftover slack into the train microbatch:
+        # full batch / half batch / skipped (tt=None), a static knob so
+        # the fused program compiles at most twice per shape
+        tt: Optional[int] = 0
+        train_rows = 0
+        if train_batch is not None:
+            ref = train_batch.get("tokens", train_batch.get("embeds"))
+            b, s = int(ref.shape[0]), int(ref.shape[1])
+            if budget is not None:
+                tt = budget.train_tokens(b, s, prefill_spent)
+            if tt is None:
+                self.stats.train_skipped_ticks += 1
+            else:
+                train_rows = b if tt == 0 else max(1, min(b, tt // s))
+        t0 = time.perf_counter()
+        with _span("collm.dispatch"):
+            logits = self._dispatch(active, tables, train_batch, tt)
+        if self.last_tick_trained:
+            self.last_tick_train_rows = train_rows
+        self.stats.decode_steps += 1
+        with _span("collm.token_pull"):
+            nxt = np.asarray(  # lint: host-sync-ok one batched argmax pull per decode wave
+                jnp.argmax(logits[:, -1], axis=-1), np.int32)
+        dt = time.perf_counter() - t0
+        if budget is not None:
+            if self.last_tick_trained:
+                # the fused tick's train share is what exceeded the
+                # known decode cost (conservative before it's known)
+                budget.observe_train(
+                    train_rows * s,
+                    max(dt - (budget.decode_tick_s or 0.0), 0.0))
+            else:
+                budget.observe_decode(dt)
+        self._record_budget(prefill_spent + dt)
+        if any(self.slot_req[i].samples for i in active):
+            # ONE batched host fetch of the last-position logits for the
+            # whole tick; greedy-only ticks keep the transfer-free
+            # device argmax path
+            with _span("collm.sample"):
+                nxt = nxt.copy()    # device-backed arrays are read-only
+                host_rows = np.asarray(logits[:, -1])  # lint: host-sync-ok one batched logits pull per sampling tick
+                for i in active:
+                    req = self.slot_req[i]
+                    if req.samples:
+                        nxt[i] = sample_token(
+                            host_rows[i],
+                            temperature=req.temperature,
+                            top_k=req.top_k, top_p=req.top_p,
+                            rng=req.rng)
+        with _span("collm.emit"):
+            for i in active:
+                req = self.slot_req[i]
+                req.tokens.append(int(nxt[i]))
+                self.stats.generated_tokens += 1
+                self.slot_pos[i] += 1
+                self.slot_tok[i] = nxt[i]
+                if len(req.tokens) >= req.max_new_tokens \
+                        or int(nxt[i]) == self.eos_id:
+                    self._record_finish(req, now)
+                    self._evict(i)
+                    finished.append(req)
+        return finished, len(active)
+
+    def _wave_tables(self, active: List[int]) -> jax.Array:
+        """Paged decode: grow each active slot's table to cover its next
+        write and return the device block tables at the wave's bucketed
+        width (re-sent only when a table or the width changed)."""
+        self._grow_tables(active)
+        width = self._table_width(active)
+        if self._dev_tables is None \
+                or self._dev_tables_width != width:
+            tbl = self.block_tables[:, :width]
+            pref = self.prefilling_slots()
+            if pref:
+                # park mid-prefill slots on scratch block 0: the paged
+                # write index CLAMPS out-of-range table lookups, so a
+                # live row here would let the parked slot's garbage
+                # decode write corrupt a real block
+                tbl = tbl.copy()
+                tbl[pref, :] = 0
+            self._dev_tables = jax.device_put(tbl, self.device)
+            self._dev_tables_width = width
+        return self._dev_tables
+
+    def _dispatch(self, active: List[int], tables: Optional[jax.Array],
+                  train_batch: Optional[Dict[str, Any]],
+                  tt: Optional[int]) -> jax.Array:
+        """Send the decode wave's inputs and run the decode program or,
+        with a planned train leg (``tt`` not None), the fused decode +
+        train program; returns the wave's logits (on the device)."""
         toks = jnp.asarray(self.slot_tok[:, None])
         pos = jnp.asarray(self.slot_pos)
         # registry mode: per-slot device adapter slots for the segmented
@@ -1879,40 +2018,6 @@ class ContinuousBatcher:
         else:
             dec_kw = {}
             comb_kw = {}
-        if self.paged:
-            self._grow_tables(active)
-            width = self._table_width(active)
-            if self._dev_tables is None \
-                    or self._dev_tables_width != width:
-                tbl = self.block_tables[:, :width]
-                pref = self.prefilling_slots()
-                if pref:
-                    # park mid-prefill slots on scratch block 0: the
-                    # paged write index CLAMPS out-of-range table
-                    # lookups, so a live row here would let the parked
-                    # slot's garbage decode write corrupt a real block
-                    tbl = tbl.copy()
-                    tbl[pref, :] = 0
-                self._dev_tables = jax.device_put(tbl, self.device)
-                self._dev_tables_width = width
-            tables = self._dev_tables
-        if self._lsan is not None:
-            self._sanitize_wave(active)
-        # budget the tick's leftover slack into the train microbatch:
-        # full batch / half batch / skipped (tt=None), a static knob so
-        # the fused program compiles at most twice per shape
-        tt: Optional[int] = 0
-        train_rows = 0
-        if train_batch is not None:
-            ref = train_batch.get("tokens", train_batch.get("embeds"))
-            b, s = int(ref.shape[0]), int(ref.shape[1])
-            if budget is not None:
-                tt = budget.train_tokens(b, s, prefill_spent)
-            if tt is None:
-                self.stats.train_skipped_ticks += 1
-            else:
-                train_rows = b if tt == 0 else max(1, min(b, tt // s))
-        t0 = time.perf_counter()
         if train_batch is not None and tt is not None:
             if self.paged:
                 (new_tl, self.opt_state, logits, self.caches,
@@ -1935,7 +2040,6 @@ class ContinuousBatcher:
             self._store_trained(new_tl)
             self._record_train(metrics)
             self.last_tick_trained = True
-            self.last_tick_train_rows = train_rows
         elif self.paged:
             logits, self.caches = self._jit_decode_paged(
                 self.params, self._serve_lora(), self.caches, toks, pos,
@@ -1945,45 +2049,7 @@ class ContinuousBatcher:
             logits, self.caches = self._jit_decode(
                 self.params, self._serve_lora(), self.caches, toks, pos,
                 attn_backend=self.attn_backend, **dec_kw)
-        self.stats.decode_steps += 1
-        nxt = np.asarray(  # lint: host-sync-ok one batched argmax pull per decode wave
-            jnp.argmax(logits[:, -1], axis=-1), np.int32)
-        dt = time.perf_counter() - t0
-        if budget is not None:
-            if self.last_tick_trained:
-                # the fused tick's train share is what exceeded the
-                # known decode cost (conservative before it's known)
-                budget.observe_train(
-                    train_rows * s,
-                    max(dt - (budget.decode_tick_s or 0.0), 0.0))
-            else:
-                budget.observe_decode(dt)
-        self._record_budget(prefill_spent + dt)
-        if any(self.slot_req[i].samples for i in active):
-            # ONE batched host fetch of the last-position logits for the
-            # whole tick; greedy-only ticks keep the transfer-free
-            # device argmax path
-            nxt = nxt.copy()    # device-backed arrays are read-only
-            host_rows = np.asarray(logits[:, -1])  # lint: host-sync-ok one batched logits pull per sampling tick
-            for i in active:
-                req = self.slot_req[i]
-                if req.samples:
-                    nxt[i] = sample_token(
-                        host_rows[i],
-                        temperature=req.temperature, top_k=req.top_k,
-                        top_p=req.top_p, rng=req.rng)
-        for i in active:
-            req = self.slot_req[i]
-            req.tokens.append(int(nxt[i]))
-            self.stats.generated_tokens += 1
-            self.slot_pos[i] += 1
-            self.slot_tok[i] = nxt[i]
-            if len(req.tokens) >= req.max_new_tokens \
-                    or int(nxt[i]) == self.eos_id:
-                self._record_finish(req, now)
-                self._evict(i)
-                finished.append(req)
-        return finished
+        return logits
 
     def _sanitize_wave(self, active: List[int]) -> None:
         """REPRO_SANITIZE=1 only (``_lsan`` gates the call): verify the
@@ -2049,7 +2115,8 @@ class ContinuousBatcher:
         self._swapped.clear()
         for r in out:
             r.tokens.clear()
-            r.prefill_at = None
+            r.first_token_at = None
+            r.admitted_wall = r.first_token_wall = None
             r.rng = None
             if self._lsan is not None:
                 self._lsan.on_drain(r)
