@@ -12,15 +12,18 @@ Two variants:
 
 ``decode_attention``        contiguous caches [B, Hkv, S, D]; grid
                             (B, Hkv, S/bk), one KV head per program.
-``paged_decode_attention``  vLLM-style paged caches: a global block pool
-                            [n_blocks, block_size, Hkv, D] shared by all
-                            sequences, walked through per-sequence block
-                            tables [B, NB] (scalar-prefetched to SMEM so
-                            the index map can DMA the right block).  A
-                            contiguous cache is the special case
-                            ``tables[b, j] = b * NB + j`` — which is
-                            exactly how ``models.layers.attention_decode``
-                            dispatches here without a layout change.
+``paged_decode_attention``  vLLM-style paged caches: the stacked block
+                            pool [L, n_blocks, block_size, Hkv, D] of every
+                            layer, shared by all sequences, walked at one
+                            layer through per-sequence block tables [B, NB]
+                            (layer and tables scalar-prefetched to SMEM so
+                            the index map DMAs block (layer, tbl[b, j])
+                            straight out of the stack: the layer scan never
+                            slices the pool).  A contiguous cache is the
+                            special case ``tables[b, j] = b * NB + j`` —
+                            which is exactly how
+                            ``models.layers.attention_decode`` dispatches
+                            here without a layout change.
 
 kv_len [B] int32 is the per-sequence valid length (ragged decode slots
 from the continuous batcher / CoLLM dispatcher subflows).
@@ -122,7 +125,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
 # =========================================================================
 # Paged variant: block-table walk over a global block pool
 # =========================================================================
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *,
                   scale: float, bs: int, nb_steps: int, hkv: int, g: int):
     b = pl.program_id(0)
@@ -139,8 +142,8 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(k_lo < kv_len)
     def _compute():
-        k = k_ref[0]                                  # [bs, Hkv, D]
-        v = v_ref[0]
+        k = k_ref[0, 0]                               # [bs, Hkv, D]
+        v = v_ref[0, 0]
         kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
         mask = kpos < kv_len
         # all KV heads of the block are resident: the block streams from
@@ -171,21 +174,25 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            v_pool: jax.Array, block_tables: jax.Array,
-                           kv_len: jax.Array, *,
+                           kv_len: jax.Array, layer=0, *,
                            scale: Optional[float] = None,
                            interpret: bool = False) -> jax.Array:
-    """q: [B,H,D]; pools: [n_blocks, block_size, Hkv, D]; block_tables:
-    [B, NB] int32; kv_len: [B] -> [B,H,D].
+    """q: [B,H,D]; pools: [L, n_blocks, block_size, Hkv, D] (a 4-D
+    [n_blocks, block_size, Hkv, D] pool is taken as L = 1); block_tables:
+    [B, NB] int32; kv_len: [B]; layer: int32 scalar, the layer of the
+    stack to read -> [B,H,D].
 
     Grid (B, NB): program (b, j) walks logical block j of sequence b by
-    DMA-ing pool block ``block_tables[b, j]`` (scalar-prefetch index
-    map), with the same online-softmax accumulator as the contiguous
-    kernel.  Table entries past a sequence's last live block must be
-    valid pool indices (the runtime points them at reserved scratch
-    block 0); ``pl.when`` skips their compute via ``kv_len``.
+    DMA-ing pool block ``(layer, block_tables[b, j])`` (scalar-prefetch
+    index map), with the same online-softmax accumulator as the
+    contiguous kernel.  Table entries past a sequence's last live block
+    must be valid pool indices (the runtime points them at reserved
+    scratch block 0); ``pl.when`` skips their compute via ``kv_len``.
     """
+    if k_pool.ndim == 4:
+        k_pool, v_pool = k_pool[None], v_pool[None]
     bsz, h, d = q.shape
-    bs, hkv = k_pool.shape[1], k_pool.shape[2]
+    bs, hkv = k_pool.shape[2], k_pool.shape[3]
     nb = block_tables.shape[1]
     g = h // hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(d)
@@ -194,18 +201,20 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     kernel = functools.partial(_paged_kernel, scale=scale, bs=bs,
                                nb_steps=nb, hkv=hkv, g=g)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(bsz, nb),
         in_specs=[
             pl.BlockSpec((1, hkv, g, d),
-                         lambda b, j, tbl, lens: (b, 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d),
-                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, bs, hkv, d),
-                         lambda b, j, tbl, lens: (tbl[b, j], 0, 0, 0)),
+                         lambda b, j, tbl, lens, lyr: (b, 0, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hkv, d),
+                         lambda b, j, tbl, lens, lyr:
+                         (lyr[0], tbl[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, 1, bs, hkv, d),
+                         lambda b, j, tbl, lens, lyr:
+                         (lyr[0], tbl[b, j], 0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, hkv, g, d),
-                               lambda b, j, tbl, lens: (b, 0, 0, 0)),
+                               lambda b, j, tbl, lens, lyr: (b, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((hkv, g), jnp.float32),
             pltpu.VMEM((hkv, g), jnp.float32),
@@ -218,5 +227,5 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         out_shape=jax.ShapeDtypeStruct((bsz, hkv, g, d), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_len.astype(jnp.int32),
-      qg, k_pool, v_pool)
+      jnp.asarray(layer, jnp.int32).reshape(1), qg, k_pool, v_pool)
     return out.reshape(bsz, h, d)

@@ -347,16 +347,17 @@ def attention_decode(q, k_cache, v_cache, kv_len, *, window: int = 0,
     return out.reshape(b, 1, hq, d).astype(q.dtype)
 
 
-def attention_decode_paged(q, k_pool, v_pool, block_tables, kv_len, *,
-                           scale: Optional[float] = None,
+def attention_decode_paged(q, k_pool, v_pool, block_tables, kv_len,
+                           layer, *, scale: Optional[float] = None,
                            backend: Optional[str] = None):
     """Single-token decode attention over a paged KV cache.
 
-    q: [B,1,Hq,D]; pools: [n_blocks, block_size, Hkv, D] (one layer's
-    slice of the global block pool); block_tables: [B, NB] int32 mapping
-    each sequence's logical blocks to pool blocks; kv_len: [B] valid
-    logical length.  Pallas backends walk the table block-by-block; the
-    jnp fallback gathers the logical [B, NB*bs] view and reuses the
+    q: [B,1,Hq,D]; pools: [L, n_blocks, block_size, Hkv, D], the stacked
+    global block pool of every layer, read at ``layer`` (int32 scalar)
+    without slicing it out; block_tables: [B, NB] int32 mapping each
+    sequence's logical blocks to pool blocks; kv_len: [B] valid logical
+    length.  Pallas backends walk the table block-by-block; the jnp
+    fallback gathers the logical [B, NB*bs] view and reuses the
     contiguous ``attention_decode`` math (identical masking, so paged
     and contiguous runtimes agree to numerical identity).
     """
@@ -367,16 +368,15 @@ def attention_decode_paged(q, k_pool, v_pool, block_tables, kv_len, *,
     if backend in ("pallas", "interpret"):
         from repro.kernels.decode_attention import paged_decode_attention
         out = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                     block_tables, klen, scale=scale,
+                                     block_tables, klen, layer,
+                                     scale=scale,
                                      interpret=backend == "interpret")
         return out[:, None].astype(q.dtype)
     b = q.shape[0]
     nb = block_tables.shape[1]
-    bs = k_pool.shape[1]
-    k = jnp.take(k_pool, block_tables, axis=0).reshape(
-        b, nb * bs, *k_pool.shape[2:])
-    v = jnp.take(v_pool, block_tables, axis=0).reshape(
-        b, nb * bs, *v_pool.shape[2:])
+    bs = k_pool.shape[2]
+    k = k_pool[layer, block_tables].reshape(b, nb * bs, *k_pool.shape[3:])
+    v = v_pool[layer, block_tables].reshape(b, nb * bs, *v_pool.shape[3:])
     return attention_decode(q, k, v, klen, scale=scale, backend="jnp")
 
 

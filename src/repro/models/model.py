@@ -20,6 +20,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro.configs.base import Family, ModelConfig, ShapeCell
@@ -686,15 +687,21 @@ class Model:
         rope_cs = rope_tables(pos[:, None], cfg.head_dim, cfg.rope_theta)
         scan = _scan_or_loop if not cfg.scan_layers else lax.scan
 
-        def body(xc, xs):
-            bp, lsl, pool_l = xs
-            y, new_pool = tfm.block_decode_paged(
-                bp, xc, cfg, pool_l, rope_cs, block_tables, write_block,
-                write_off, kv_len, lora=lsl, backend=attn_backend,
-                adapter_idx=adapter_idx)
-            return y, new_pool
+        # the stacked pool rides in the carry: each layer writes its
+        # token in place and the kernel reads (layer, block) straight out
+        # of the stack, so no layer's pool is sliced out or stacked back
+        def body(carry, xs):
+            xc, pool = carry
+            bp, lsl, layer = xs
+            y, pool = tfm.block_decode_paged(
+                bp, xc, cfg, pool, layer, rope_cs, block_tables,
+                write_block, write_off, kv_len, lora=lsl,
+                backend=attn_backend, adapter_idx=adapter_idx)
+            return (y, pool), None
 
-        x, new_kv = scan(body, x, (params["blocks"], lora, caches["kv"]))
+        layers = np.arange(cfg.n_layers, dtype=np.int32)
+        (x, new_kv), _ = scan(body, (x, caches["kv"]),
+                              (params["blocks"], lora, layers))
         hidden = rms_norm(x, params["final_norm"])
         logits = _lm_head(hidden, params)
         return logits, {"kv": new_kv}

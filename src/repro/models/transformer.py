@@ -225,34 +225,39 @@ def attn_decode(p, x, cfg: ModelConfig, cache_kv, pos, rope_cs, lora=None,
     return _proj_out(p, o, cfg, lora, adapter_idx), (k_cache, v_cache)
 
 
-def attn_decode_paged(p, x, cfg: ModelConfig, pool_kv, rope_cs,
+def attn_decode_paged(p, x, cfg: ModelConfig, pool_kv, layer, rope_cs,
                       block_tables, write_block, write_off, kv_len,
                       lora=None, backend=None, adapter_idx=None):
-    """One-token attention against one layer's paged KV block pool.
+    """One-token attention of layer ``layer`` against the stacked paged
+    KV block pool.
 
-    pool_kv: (k_pool, v_pool) [n_blocks, block_size, Hkv, Dh];
-    block_tables: [B, NB] int32; write_block/write_off: [B] int32 pool
-    block id and in-block offset where each sequence's new K/V lands
-    (precomputed once per step from the ragged positions — ring
-    addressing for sliding-window archs included); kv_len: [B] valid
-    logical length AFTER the write.  Returns (out, updated pools)."""
+    pool_kv: (k_pool, v_pool) [L, n_blocks, block_size, Hkv, Dh], every
+    layer's pool in one buffer, addressed by (layer, block) and never
+    sliced; layer: int32 scalar (traced under the layer scan, a Python
+    int when unrolled); block_tables: [B, NB] int32; write_block /
+    write_off: [B] int32 pool block id and in-block offset where each
+    sequence's new K/V lands (precomputed once per step from the ragged
+    positions — ring addressing for sliding-window archs included);
+    kv_len: [B] valid logical length AFTER the write.  Returns (out,
+    updated pools)."""
     k_pool, v_pool = pool_kv
     q, k, v = _proj_qkv(p, x, cfg, lora, adapter_idx)
     if rope_cs is not None:
         cos, sin = rope_cs
         q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
-    # scatter the new token's K/V into each sequence's current block —
-    # distinct sequences own distinct blocks, so indices never collide
-    # (inactive slots share scratch block 0, where last-write-wins
-    # garbage is fine: their logits are discarded)
+    # scatter the new token's K/V into each sequence's current block of
+    # this layer, in place in the stacked pool — distinct sequences own
+    # distinct blocks, so indices never collide (inactive slots share
+    # scratch block 0, where last-write-wins garbage is fine: their
+    # logits are discarded)
     with jax.named_scope("kv_write"):
-        k_pool = k_pool.at[write_block, write_off].set(
+        k_pool = k_pool.at[layer, write_block, write_off].set(
             k[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[write_block, write_off].set(
+        v_pool = v_pool.at[layer, write_block, write_off].set(
             v[:, 0].astype(v_pool.dtype))
     with jax.named_scope("attn"):
         o = attention_decode_paged(q, k_pool, v_pool, block_tables,
-                                   kv_len, backend=backend)
+                                   kv_len, layer, backend=backend)
     return _proj_out(p, o, cfg, lora, adapter_idx), (k_pool, v_pool)
 
 
@@ -395,16 +400,16 @@ def block_decode(bp, x, cfg: ModelConfig, caches, pos, rope_cs, lora=None,
     return x, new_caches
 
 
-def block_decode_paged(bp, x, cfg: ModelConfig, pool_kv, rope_cs,
+def block_decode_paged(bp, x, cfg: ModelConfig, pool_kv, layer, rope_cs,
                        block_tables, write_block, write_off, kv_len,
                        lora=None, backend=None, adapter_idx=None):
-    """One-token block against one layer's paged KV pool (attention-only
-    stacks — SSM state is per-slot, not per-block).  Returns
-    (x, updated pools)."""
+    """One-token block of layer ``layer`` against the stacked paged KV
+    pool [L, n_blocks, block_size, Hkv, Dh] (attention-only stacks — SSM
+    state is per-slot, not per-block).  Returns (x, updated pools)."""
     h = rms_norm(x, bp["ln1"])
     attn_out, new_kv = attn_decode_paged(
-        bp["attn"], h, cfg, pool_kv, rope_cs, block_tables, write_block,
-        write_off, kv_len, lora=lora, backend=backend,
+        bp["attn"], h, cfg, pool_kv, layer, rope_cs, block_tables,
+        write_block, write_off, kv_len, lora=lora, backend=backend,
         adapter_idx=adapter_idx)
     x = x + attn_out
     if cfg.d_ff > 0:

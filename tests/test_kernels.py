@@ -63,27 +63,44 @@ def test_decode_attention(b, h, hkv, s, d):
                                rtol=2e-5, atol=2e-5)
 
 
-@pytest.mark.parametrize("b,h,hkv,nb_pool,bs,nb,d", [
-    (2, 8, 2, 16, 16, 4, 64),       # GQA, short tables
-    (3, 4, 4, 12, 8, 8, 128),       # MHA, longer walk
-    (1, 16, 2, 32, 32, 6, 64),      # wide grouping
+@pytest.mark.parametrize("b,h,hkv,nb_pool,bs,nb,d,n_layers,layer", [
+    pytest.param(2, 8, 2, 16, 16, 4, 64, None, 0,
+                 id="2-8-2-16-16-4-64"),      # GQA, short tables
+    pytest.param(3, 4, 4, 12, 8, 8, 128, None, 0,
+                 id="3-4-4-12-8-8-128"),      # MHA, longer walk
+    pytest.param(1, 16, 2, 32, 32, 6, 64, None, 0,
+                 id="1-16-2-32-32-6-64"),     # wide grouping
+    # stacked [L, n_blocks, bs, Hkv, D] pool read at one layer
+    pytest.param(2, 8, 2, 16, 16, 4, 64, 3, 0, id="stacked-gqa-layer0"),
+    pytest.param(2, 8, 2, 16, 16, 4, 64, 3, 2, id="stacked-gqa-layer2"),
+    pytest.param(3, 4, 4, 12, 8, 8, 128, 3, 0, id="stacked-mha-layer0"),
+    pytest.param(3, 4, 4, 12, 8, 8, 128, 3, 2, id="stacked-mha-layer2"),
 ])
-def test_paged_decode_attention(b, h, hkv, nb_pool, bs, nb, d):
+def test_paged_decode_attention(b, h, hkv, nb_pool, bs, nb, d, n_layers,
+                                layer):
     """Block-table walk over a shuffled pool == dense attention over
-    the gathered logical cache."""
+    the gathered logical cache (of ``pool[layer]`` for a stacked pool;
+    ``n_layers`` None is the 4-D single-layer pool)."""
     ks = jax.random.split(jax.random.key(6), 4)
     q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (nb_pool, bs, hkv, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (nb_pool, bs, hkv, d), jnp.float32)
+    lead = () if n_layers is None else (n_layers,)
+    kp = jax.random.normal(ks[1], lead + (nb_pool, bs, hkv, d), jnp.float32)
+    vp = jax.random.normal(ks[2], lead + (nb_pool, bs, hkv, d), jnp.float32)
     rng = np.random.default_rng(7)
     # distinct non-scratch blocks per sequence, shuffled pool order
     tables = np.stack([rng.permutation(np.arange(1, nb_pool))[:nb]
                        for _ in range(b)]).astype(np.int32)
     kl = jax.random.randint(ks[3], (b,), 1, nb * bs + 1)
-    y = paged_decode_attention(q, kp, vp, jnp.asarray(tables), kl,
-                               interpret=True)
-    k_log = kp[tables].reshape(b, nb * bs, hkv, d).transpose(0, 2, 1, 3)
-    v_log = vp[tables].reshape(b, nb * bs, hkv, d).transpose(0, 2, 1, 3)
+    if n_layers is None:
+        y = paged_decode_attention(q, kp, vp, jnp.asarray(tables), kl,
+                                   interpret=True)
+        k_l, v_l = kp, vp
+    else:
+        y = paged_decode_attention(q, kp, vp, jnp.asarray(tables), kl,
+                                   jnp.int32(layer), interpret=True)
+        k_l, v_l = kp[layer], vp[layer]
+    k_log = k_l[tables].reshape(b, nb * bs, hkv, d).transpose(0, 2, 1, 3)
+    v_log = v_l[tables].reshape(b, nb * bs, hkv, d).transpose(0, 2, 1, 3)
     yr = ref.decode_attention(q, k_log, v_log, kl)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                rtol=2e-5, atol=2e-5)

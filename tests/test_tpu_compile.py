@@ -2,11 +2,16 @@
 attached: qwen1.5-0.5b at its published widths in bf16, 16 slots of
 1,024 tokens in a paged pool of 1,025 blocks of 16.  Nothing runs; the
 chip's compiler must accept each program, keep the Pallas paged-decode
-kernel in it (``tpu_custom_call``), and fit it in one chip's 16 GB.
+kernel in it (``tpu_custom_call``), and fit it in one chip's 16 GB.  The
+decode and fused programs, compiled with the pool donated as the server
+compiles them, must hand the pool back in place and never slice a
+layer's pool out of the stack or stack it back (``_pool_ops``).
 
 The topology is described inside a fixture, never at import time: only
 one process may load the TPU library, and every test worker imports
 this file."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -81,12 +86,59 @@ def _decode_args(model, sharding):
             _sds((SLOTS,), i32, sharding))
 
 
-def _check(compiled):
-    assert "tpu_custom_call" in compiled.as_text(), \
+_INSTR = re.compile(r"%(\S+) = \w+\[([\d,]*)\]\{([^}]*)\}\S* "
+                    r"([\w-]+)\(([^)]*)\)")
+
+
+def _pool_ops(hlo: str, hkv: int, d: int, relayout: bool = True):
+    """The ops of a compiled program that move a pool-shaped array: any
+    ``dynamic-slice`` / ``dynamic-update-slice`` of a pool, any ``copy``
+    of one layer's pool ([N, BLOCK, hkv, d] or [1, N, BLOCK, hkv, d]),
+    and any ``copy`` of the whole stacked pool that keeps its layout.  A
+    whole-pool copy that changes the layout (the pool's default device
+    layout into the kernel's row-major one and back) is the one copy
+    the stacked pool may take; the new token's write is a ``scatter``."""
+    pool = [N_BLOCKS, BLOCK, hkv, d]
+    defs, bad = {}, []
+    for name, dims, layout, op, args in _INSTR.findall(hlo):
+        shape = [int(x) for x in dims.split(",") if x]
+        minor_to_major = layout.split(":")[0]
+        defs[name] = minor_to_major
+        if shape[-4:] != pool or len(shape) > 5:
+            continue
+        per_layer = len(shape) == 4 or shape[0] == 1
+        if op in ("dynamic-slice", "dynamic-update-slice"):
+            bad.append(f"{op} {name} {shape}")
+        elif op == "copy":
+            src = defs.get(args.strip().lstrip("%"))
+            if (per_layer or not relayout or src is None
+                    or src == minor_to_major):
+                bad.append(f"copy {name} {shape} {{{src}}}->"
+                           f"{{{minor_to_major}}}")
+    return bad
+
+
+def _pool_aliased(hlo: str) -> bool:
+    """Every whole-pool entry parameter is aliased to an output."""
+    entry = hlo[hlo.index("\nENTRY "):]
+    params = re.findall(r"= \w+\[\d+," + str(N_BLOCKS)
+                        + r",[\d,]*\]\{[^}]*\} parameter\((\d+)\)", entry)
+    aliased = re.findall(r"\{\d+\}: \((\d+), \{\}",
+                         hlo.split("\n", 1)[0])
+    return bool(params) and set(params) <= set(aliased)
+
+
+def _check(compiled, pool=None):
+    """``pool`` = (hkv, d) for a program that carries the stacked pool."""
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo, \
         "the Pallas paged-decode kernel is missing from the program"
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, f"{used / 1e9:.2f} GB does not fit one chip"
+    if pool is not None:
+        assert _pool_ops(hlo, *pool) == [], _pool_ops(hlo, *pool)
+        assert _pool_aliased(hlo), "the pool is not updated in place"
 
 
 def test_paged_decode_attention_compiles(one_chip, engine):
@@ -106,11 +158,11 @@ def test_decode_step_paged_compiles(one_chip, engine):
     params, stacked, caches, tok, pos, tbl, idx = _decode_args(model,
                                                                one_chip)
     compiled = jax.jit(
-        model.decode_step_paged,
+        model.decode_step_paged, donate_argnums=(2,),
         static_argnames=("ring_len", "attn_backend")).lower(
         params, stacked, caches, tok, pos, tbl, ring_len=MAX_SEQ,
         attn_backend="pallas", adapter_idx=idx).compile()
-    _check(compiled)
+    _check(compiled, pool=(model.cfg.n_kv_heads, model.cfg.head_dim))
 
 
 def test_combined_step_paged_compiles(one_chip, engine):
@@ -124,9 +176,27 @@ def test_combined_step_paged_compiles(one_chip, engine):
              "labels": _sds((TRAIN_BATCH, TRAIN_SEQ), jnp.int32, one_chip),
              "mask": _sds((TRAIN_BATCH, TRAIN_SEQ), jnp.float32, one_chip)}
     compiled = jax.jit(
-        engine.combined_step_paged,
+        engine.combined_step_paged, donate_argnums=(2, 4),
         static_argnames=("ring_len", "attn_backend")).lower(
         params, lora, opt, batch, caches, tok, pos, tbl, ring_len=MAX_SEQ,
         serve_lora=stacked, attn_backend="pallas",
         serve_adapter_idx=idx).compile()
-    _check(compiled)
+    _check(compiled, pool=(model.cfg.n_kv_heads, model.cfg.head_dim))
+
+
+def test_gqa_decode_step_paged_keeps_the_pool_in_place(one_chip):
+    """internlm2-1.8b (16 query over 8 KV heads of 128): the pool's
+    default v5e layout is already the kernel's row-major one, so the
+    decode program takes no copy of it at all."""
+    model = make_engine(get_config("internlm2-1.8b")).model
+    params, stacked, caches, tok, pos, tbl, idx = _decode_args(model,
+                                                               one_chip)
+    compiled = jax.jit(
+        model.decode_step_paged, donate_argnums=(2,),
+        static_argnames=("ring_len", "attn_backend")).lower(
+        params, stacked, caches, tok, pos, tbl, attn_backend="pallas",
+        adapter_idx=idx).compile()
+    cfg = model.cfg
+    _check(compiled, pool=(cfg.n_kv_heads, cfg.head_dim))
+    assert _pool_ops(compiled.as_text(), cfg.n_kv_heads, cfg.head_dim,
+                     relayout=False) == []
