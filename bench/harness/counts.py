@@ -1,87 +1,9 @@
-"""Operations and bytes the work needs, computed from shapes: what the
-rooflines and the ``mfu`` metrics divide by.  Only the work a request
-needs counts: real (unpadded) prompt tokens, decode rows that hold a
-request, their live context, and no recomputation.  A matrix product
-of [m, k] by [k, n] is 2*m*k*n operations; softmax, norms and
-elementwise work are not counted.
-"""
+"""The roofline arithmetic every kernel metric shares.  What a kernel
+or a tick needs in operations and bytes is counted by the model's
+family (``bench/families/<family>.py``)."""
 from __future__ import annotations
 
-from typing import Any, Dict, Iterable
-
-from harness.weights import dims, lora_targets
-
-
-def _lora_per_token(cfg: Dict[str, Any]) -> int:
-    r = dims(cfg)["r"]
-    return sum(2 * r * (din + dout)
-               for din, dout in lora_targets(cfg).values())
-
-
-def linear_per_token(cfg: Dict[str, Any]) -> int:
-    """Operations of every layer's projections and feed-forward, and
-    the served adapter, for one token (the output head excluded)."""
-    m = dims(cfg)
-    d, H, Hkv, hd, f = m["d"], m["H"], m["Hkv"], m["hd"], m["f"]
-    per_layer = 2 * d * (2 * H * hd + 2 * Hkv * hd) + 2 * 3 * d * f \
-        + _lora_per_token(cfg)
-    return m["L"] * per_layer
-
-
-def head_per_token(cfg: Dict[str, Any]) -> int:
-    m = dims(cfg)
-    return 2 * m["d"] * m["V"]
-
-
-def attention_ops(cfg: Dict[str, Any], context: int) -> int:
-    """Scores and weighted sum of one query token over ``context`` keys,
-    all layers."""
-    m = dims(cfg)
-    return m["L"] * 4 * m["H"] * m["hd"] * context
-
-
-def prefill_ops(cfg: Dict[str, Any], prompt_lens: Iterable[int]) -> int:
-    """Prefill of real prompt tokens: every token's layers and causal
-    attention, the output head at each prompt's last token only."""
-    total = 0
-    for n in prompt_lens:
-        total += n * linear_per_token(cfg) + head_per_token(cfg)
-        total += attention_ops(cfg, n * (n + 1) // 2)
-    return total
-
-
-def decode_ops(cfg: Dict[str, Any], contexts: Iterable[int]) -> int:
-    """One decode tick over live rows, each at its context length."""
-    total = 0
-    for ctx in contexts:
-        total += linear_per_token(cfg) + head_per_token(cfg) \
-            + attention_ops(cfg, ctx)
-    return total
-
-
-def train_ops(cfg: Dict[str, Any], rows: int, seq: int) -> int:
-    """The train leg: forward (head at every position) and the input
-    gradients, which cost the forward again, plus the adapter's weight
-    gradients.  The frozen base weights get no weight gradients."""
-    tokens = rows * seq
-    fwd = tokens * (linear_per_token(cfg) + head_per_token(cfg)) \
-        + rows * attention_ops(cfg, seq * (seq + 1) // 2)
-    return 2 * fwd + tokens * dims(cfg)["L"] * _lora_per_token(cfg)
-
-
-def paged_attention(cfg: Dict[str, Any], contexts: Iterable[int],
-                    kv_bytes: int = 2) -> Dict[str, int]:
-    """What ``paged_decode_attention`` needs for live rows at their
-    context lengths, over all layers: 4*H*hd operations per key, and
-    each live key and value read once plus each query read and output
-    written once."""
-    m = dims(cfg)
-    ctx = list(contexts)
-    keys = sum(ctx)
-    ops = m["L"] * 4 * m["H"] * m["hd"] * keys
-    nbytes = m["L"] * (2 * keys * m["Hkv"] * m["hd"] * kv_bytes
-                       + 2 * len(ctx) * m["H"] * m["hd"] * kv_bytes)
-    return {"ops": ops, "bytes": nbytes}
+from typing import Any, Dict
 
 
 def roofline_share(ops: float, nbytes: float, seconds: float,

@@ -11,7 +11,7 @@ from __future__ import annotations
 import importlib.util
 from typing import Any, Callable, Dict, List, Optional
 
-from harness import counts, spec, tracing
+from harness import spec, tracing
 
 
 class Context:
@@ -60,14 +60,15 @@ class Context:
         """Operations the traced ticks' live work needs: real prompt
         tokens, live decode rows at their contexts and, where a tick
         trained, its train leg."""
-        ops = counts.prefill_ops(self.cfg, self.prompt_lens()) \
-            + counts.decode_ops(self.cfg, self.decode_contexts())
+        fam = spec.family(self.cfg)
+        ops = fam.prefill_ops(self.cfg, self.prompt_lens()) \
+            + fam.decode_ops(self.cfg, self.decode_contexts())
         train = self.mix.get("train")
         if train:
             seq = int(train["seq"])
             for t in self.traced_ticks:
                 if t[2]:
-                    ops += counts.train_ops(self.cfg, t[3], seq)
+                    ops += fam.train_ops(self.cfg, t[3], seq)
         return float(ops)
 
     def mfu_pct(self) -> Optional[float]:

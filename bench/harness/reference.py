@@ -1,23 +1,19 @@
-"""The plain reference: a dense decoder in float32 ``jax.numpy`` with
-LoRA on the attention projections, written from the published
-description (RMSNorm, rotary embeddings on half-split head dims,
-grouped-query attention with query head i reading key/value head
-i // (H / Hkv), SwiGLU feed-forward), and AdamW with global-norm
-clipping for the train leg.  It imports nothing of the program and
-takes no array the program made: weights and adapters are made again
-from the run's seed (``weights.py``).
+"""What every family's plain reference shares: float32 building blocks
+(matrix products at ``precision="highest"``, RMSNorm, rotary
+embeddings, a projection with its LoRA pair), the fp8 control, and
+AdamW with global-norm clipping for the train leg.  The decoders
+themselves are ``bench/families/<family>.py``.
 
-Matrix products run at ``precision="highest"``.  ``quant="fp8"`` is the
-control: every linear layer's input and weight are rounded to
-float8_e4m3fn (absmax scale per tensor for weights, per row for
-activations) before an f32 product, the step below the bf16 the
+``quant="fp8"`` is the control: every linear layer's input and weight
+are rounded to float8_e4m3fn (absmax scale per tensor for weights, per
+row for activations) before an f32 product, the step below the bf16 the
 configurations state; its gradients are taken straight through the
 rounding.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +23,7 @@ F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
 
 
-def _q8(x, axis):
+def q8(x, axis):
     """Round to float8_e4m3fn with an absmax scale over ``axis``.  The
     gradient passes straight through the rounding, as in fp8 training,
     where the backward products take the rounded operands; a cast's
@@ -39,19 +35,19 @@ def _q8(x, axis):
     return x + lax.stop_gradient(q - x)
 
 
-def _mm(x, w, quant):
+def mm(x, w, quant):
     if quant == "fp8":
-        x = _q8(x, -1)
-        w = _q8(w, None)
+        x = q8(x, -1)
+        w = q8(w, None)
     return jnp.matmul(x, w, precision=lax.Precision.HIGHEST)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     return x * lax.rsqrt(var + eps) * scale
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     """x [..., S, H, D]; rotate the two halves of each head."""
     half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
@@ -61,81 +57,15 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _linear(x, w, bias, pair, scaling, quant):
-    y = _mm(x, w, quant)
+def linear(x, w, bias, pair, scaling, quant):
+    """``x @ w``, plus the LoRA ``pair`` (``{"a", "b"}``) times
+    ``scaling`` and ``bias`` where given."""
+    y = mm(x, w, quant)
     if pair is not None:
-        y = y + _mm(_mm(x, pair["a"], quant), pair["b"], quant) * scaling
+        y = y + mm(mm(x, pair["a"], quant), pair["b"], quant) * scaling
     if bias is not None:
         y = y + bias
     return y
-
-
-def hidden(params, adapter, tokens, cfg: Dict[str, Any], quant=None):
-    """Final-norm hidden states [B, S, d] for token rows [B, S]
-    (causal; a row's padding past its end touches no earlier
-    position)."""
-    H, Hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
-    d = cfg["hidden_size"]
-    hd = cfg.get("head_dim", d // H)
-    eps = float(cfg["rms_norm_eps"])
-    theta = float(cfg["rope_theta"])
-    sc = float(cfg["lora_alpha"]) / float(cfg["lora_rank"])
-    f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
-    b, s = tokens.shape
-    pos = jnp.arange(s)
-    mask = pos[None, :] <= pos[:, None]
-    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-    def layer(x, xs):
-        bp, ad = f32(xs[0]), xs[1]
-        at = bp["attn"]
-        h = _rms(x, bp["ln1"], eps)
-        q = _linear(h, at["wq"], at.get("bq"), ad.get("q"), sc, quant)
-        k = _linear(h, at["wk"], at.get("bk"), ad.get("k"), sc, quant)
-        v = _linear(h, at["wv"], at.get("bv"), ad.get("v"), sc, quant)
-        q = _rope(q.reshape(b, s, H, hd), pos, theta)
-        k = _rope(k.reshape(b, s, Hkv, hd), pos, theta)
-        v = v.reshape(b, s, Hkv, hd)
-        k = jnp.repeat(k, H // Hkv, axis=2)
-        v = jnp.repeat(v, H // Hkv, axis=2)
-        att = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                         precision=lax.Precision.HIGHEST) / jnp.sqrt(
-                             jnp.float32(hd))
-        att = jax.nn.softmax(jnp.where(mask, att, -jnp.inf), axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", att, v,
-                       precision=lax.Precision.HIGHEST).reshape(b, s, H * hd)
-        x = x + _linear(o, at["wo"], None, ad.get("o"), sc, quant)
-        h = _rms(x, bp["ln2"], eps)
-        mlp = bp["mlp"]
-        g = _mm(h, mlp["wg"], quant)
-        u = _mm(h, mlp["wu"], quant)
-        x = x + _mm(jax.nn.silu(g) * u, mlp["wd"], quant)
-        return x, None
-
-    x, _ = lax.scan(layer, x, (params["blocks"], f32(adapter)))
-    return _rms(x, params["final_norm"].astype(jnp.float32), eps)
-
-
-def _logits(params, h, quant):
-    return _mm(h, params["lm_head"].astype(jnp.float32), quant)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "quant"))
-def _served_gaps(params, adapter, tokens, served, cfg_items, quant):
-    """Per position p of ``tokens`` [S]: how far the reference logit of
-    ``served[p]`` lies below the reference's best, and the same for the
-    token the ``quant`` control would put first (None: no control)."""
-    cfg = dict(cfg_items)
-    h = hidden(params, adapter, tokens[None], cfg)[0]
-    ref = _logits(params, h, None)
-    best = jnp.max(ref, axis=-1)
-    gap = best - jnp.take_along_axis(ref, served[:, None], axis=1)[:, 0]
-    if quant is None:
-        return gap, jnp.zeros_like(gap)
-    hc = hidden(params, adapter, tokens[None], cfg, quant)[0]
-    pick = jnp.argmax(_logits(params, hc, quant), axis=-1)
-    ctrl = best - jnp.take_along_axis(ref, pick[:, None], axis=1)[:, 0]
-    return gap, ctrl
 
 
 def cfg_key(cfg: Dict[str, Any]) -> tuple:
@@ -144,46 +74,10 @@ def cfg_key(cfg: Dict[str, Any]) -> tuple:
                         if isinstance(v, (int, float, str, bool))))
 
 
-def served_gaps(params, adapter, prompt, tokens, pad_to: int,
-                cfg: Dict[str, Any], quant: Optional[str] = None):
-    """Teacher-force ``prompt + tokens[:-1]`` (padded to ``pad_to``) and
-    return, for each served token, its gap below the reference's best
-    logit (and the control's gaps when ``quant`` is set)."""
-    import numpy as np
-    seq = np.concatenate([prompt, tokens[:-1]]).astype(np.int32)
-    served = np.zeros(pad_to, np.int32)
-    start = len(prompt) - 1
-    served[start:start + len(tokens)] = tokens
-    padded = np.zeros(pad_to, np.int32)
-    padded[:len(seq)] = seq
-    gap, ctrl = _served_gaps(params, adapter, jnp.asarray(padded),
-                             jnp.asarray(served), cfg_key(cfg), quant)
-    gap, ctrl = jax.device_get((gap, ctrl))
-    sl = slice(start, start + len(tokens))
-    return np.asarray(gap[sl]), np.asarray(ctrl[sl])
-
-
-# --------------------------------------------------------------- train --
-def loss(params, adapter, batch, cfg: Dict[str, Any], quant=None):
-    """Mean next-token cross-entropy over the batch's mask."""
-    h = hidden(params, adapter, batch["tokens"], cfg, quant)
-    logits = _logits(params, h, quant)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    ll = jnp.take_along_axis(logits, batch["labels"][..., None],
-                             axis=-1)[..., 0]
-    m = batch["mask"].astype(jnp.float32)
-    return jnp.sum((logz - ll) * m) / jnp.maximum(jnp.sum(m), 1.0)
-
-
-@functools.partial(jax.jit, static_argnames=("cfg_items", "quant"))
-def _loss_and_grad(params, adapter, batch, cfg_items, quant):
-    cfg = dict(cfg_items)
-    return jax.value_and_grad(loss, argnums=1)(params, adapter, batch, cfg,
-                                               quant)
-
-
 @functools.partial(jax.jit, static_argnames=("opt_items",))
-def _adamw(adapter, grads, state, opt_items):
+def adamw(adapter, grads, state, opt_items):
+    """``adamw_step`` compiled, the optimizer's settings as sorted
+    items."""
     return adamw_step(adapter, grads, state, dict(opt_items))
 
 
@@ -208,22 +102,3 @@ def adamw_step(adapter, grads, state, opt: Dict[str, Any]):
         lambda p, mm, vv: p - lr * (mm / bc1) / (jnp.sqrt(vv / bc2) + eps),
         adapter, m, v)
     return new, {"t": t, "m": m, "v": v}, g
-
-
-def train_steps(params, adapter, batches, cfg: Dict[str, Any],
-                opt: Dict[str, Any], quant: Optional[str] = None):
-    """Follow the program's first train steps: returns the loss of each
-    step, the first clipped gradient, and the adapter after them all."""
-    state = {"t": jnp.zeros((), jnp.int32),
-             "m": jax.tree.map(jnp.zeros_like, adapter),
-             "v": jax.tree.map(jnp.zeros_like, adapter)}
-    opt_items = tuple(sorted(opt.items()))
-    losses, first_grad = [], None
-    for batch in batches:
-        lval, grads = _loss_and_grad(params, adapter, batch, cfg_key(cfg),
-                                     quant)
-        adapter, state, g = _adamw(adapter, grads, state, opt_items)
-        losses.append(float(lval))
-        if first_grad is None:
-            first_grad = g
-    return losses, first_grad, adapter

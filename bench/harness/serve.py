@@ -25,7 +25,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from harness import reference, stats, tracing, weights
+from harness import spec, stats, tracing
 from harness import traffic as gen
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -62,23 +62,6 @@ class CompileCounter:
 
 
 # ---------------------------------------------------------------- build --
-def model_config(cfg: Dict[str, Any]):
-    """The program's ``ModelConfig`` for a configuration file: the
-    registry entry of ``arch`` with every size the file states."""
-    from repro.configs.base import LoRAConfig
-    from repro.configs.registry import get_config
-    m = weights.dims(cfg)
-    return dataclasses.replace(
-        get_config(cfg["arch"]), n_layers=m["L"], d_model=m["d"],
-        n_heads=m["H"], n_kv_heads=m["Hkv"], head_dim=m["hd"],
-        d_ff=m["f"], vocab_size=m["V"],
-        qkv_bias=bool(cfg.get("qkv_bias", False)),
-        rope_theta=float(cfg["rope_theta"]), sliding_window=0,
-        dtype=cfg["torch_dtype"], param_dtype=cfg["torch_dtype"],
-        lora=LoRAConfig(rank=m["r"], alpha=float(cfg["lora_alpha"]),
-                        targets=tuple(cfg["lora_targets"])))
-
-
 def engine_for(cfg: Dict[str, Any], mix: Dict[str, Any]):
     from repro.core.engine import Engine
     from repro.models.model import build
@@ -88,7 +71,8 @@ def engine_for(cfg: Dict[str, Any], mix: Dict[str, Any]):
         lr=float(opt["lr"]), b1=float(opt["b1"]), b2=float(opt["b2"]),
         eps=float(opt["eps"]), weight_decay=0.0,
         clip_norm=float(opt["clip_norm"]))
-    return Engine(model=build(model_config(cfg)), optimizer=adamw)
+    return Engine(model=build(spec.family(cfg).program_config(cfg)),
+                  optimizer=adamw)
 
 
 @dataclasses.dataclass
@@ -115,7 +99,8 @@ def build_program(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
         ContinuousBatcher
     engine = engine or engine_for(cfg, mix)
     model = engine.model
-    params = weights.make_params(cfg, seed)
+    fam = spec.family(cfg)
+    params = fam.make_params(cfg, seed)
     want = jax.tree.map(lambda s: (s.shape, s.dtype.name),
                         jax.eval_shape(model.init, jax.random.key(0)))
     got = jax.tree.map(lambda a: (a.shape, a.dtype.name), params)
@@ -124,7 +109,7 @@ def build_program(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
                          f"program {want}\nbench {got}")
     n_ten = int(mix["tenants"])
     registry = AdapterRegistry(model, capacity=n_ten)
-    tenants = [weights.make_adapter(cfg, seed, t) for t in range(n_ten)]
+    tenants = [fam.make_adapter(cfg, seed, t) for t in range(n_ten)]
     for t, tree in enumerate(tenants):
         registry.register(f"tenant{t}", tree)
     train = mix.get("train")
@@ -139,7 +124,7 @@ def build_program(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
         tpot_target=float(mix["slo"]["tpot_ms"]) / 1e3 if train else 0.0)
     batches = []
     if train:
-        pool = gen.train_pool(mix, seed, weights.dims(cfg)["V"],
+        pool = gen.train_pool(mix, seed, fam.dims(cfg)["V"],
                               int(train["pool"]))
         for k in range(int(train["pool"])):
             batches.append({key: jnp.asarray(v[k])
@@ -485,14 +470,15 @@ def served_check(cfg: Dict[str, Any], seed: int, picks: List[Rec],
                  pad_to: int, control: bool) -> Dict[str, Any]:
     """Widest gap of a served token's reference logit below the
     reference's best, over the sample (and the control's, when asked)."""
-    params = weights.make_params(cfg, seed)
+    fam = spec.family(cfg)
+    params = fam.make_params(cfg, seed)
     adapters = {}
     gap, ctrl, n = 0.0, 0.0, 0
     for r in picks:
         t = r.arrival.tenant
         if t not in adapters:
-            adapters[t] = weights.make_adapter(cfg, seed, t)
-        g, c = reference.served_gaps(
+            adapters[t] = fam.make_adapter(cfg, seed, t)
+        g, c = fam.served_gaps(
             params, adapters[t], r.arrival.prompt,
             np.asarray(r.req.tokens, np.int32), pad_to, cfg,
             "fp8" if control else None)
@@ -524,19 +510,20 @@ def train_check(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
     with half its rows left out in the reference's place."""
     train = mix["train"]
     opt = train["optimizer"]
-    pool = gen.train_pool(mix, seed, weights.dims(cfg)["V"],
+    fam = spec.family(cfg)
+    pool = gen.train_pool(mix, seed, fam.dims(cfg)["V"],
                           int(train["pool"]))
     batches = [{k: jnp.asarray(v[s["index"]][:s["rows"]])
                 for k, v in pool.items()} for s in check.batches]
-    params = weights.make_params(cfg, seed)
-    lora0 = weights.make_adapter(cfg, seed, 0)
+    params = fam.make_params(cfg, seed)
+    lora0 = fam.make_adapter(cfg, seed, 0)
 
     def read(losses, grad, adapter):
         gn = leaf_norms(grad)
         ch = leaf_diff_norms(adapter, lora0)
         return losses, gn, ch
 
-    ref_l, ref_g, ref_c = read(*reference.train_steps(
+    ref_l, ref_g, ref_c = read(*fam.train_steps(
         params, lora0, batches, cfg, opt))
     med_g = float(np.median(list(ref_g.values())))
     moving = [k for k, v in ref_g.items() if v >= 1e-3 * med_g]
@@ -550,12 +537,12 @@ def train_check(cfg: Dict[str, Any], mix: Dict[str, Any], seed: int,
     out = gaps(check.losses, check.first_grad, check.change)
     out["left_out_leaves"] = len(ref_g) - len(moving)
     if faults:
-        ctl = read(*reference.train_steps(params, lora0, batches, cfg, opt,
-                                          quant="fp8"))
+        ctl = read(*fam.train_steps(params, lora0, batches, cfg, opt,
+                                    quant="fp8"))
         out["control"] = gaps(*ctl)
         half = [{k: v[:max(1, v.shape[0] // 2)] for k, v in bt.items()}
                 for bt in batches]
-        out["half_batch"] = gaps(*read(*reference.train_steps(
+        out["half_batch"] = gaps(*read(*fam.train_steps(
             params, lora0, half, cfg, opt)))
     return out
 
@@ -567,7 +554,8 @@ def run_once(cell, seed: int, seconds: float, trace: bool, clock,
     """Set up, measure and check one run; returns everything the result
     line and the per-layer readers need."""
     cfg, mix = cell.config, cell.traffic
-    vocab = weights.dims(cfg)["V"]
+    fam = spec.family(cfg)
+    vocab = fam.dims(cfg)["V"]
     t_build = clock()
     prog = build_program(cfg, mix, seed, engine)
     t_warm = clock()
@@ -589,6 +577,9 @@ def run_once(cell, seed: int, seconds: float, trace: bool, clock,
     res["warm_programs"] = warm_programs
     res["metrics"] = window_metrics(res, seconds, mix)
     res["slots"] = prog.batcher.n_slots
+    res["model"] = {"family": cfg["family"],
+                    "base_params": fam.param_count(cfg),
+                    "adapter_targets": sorted(fam.lora_targets(cfg))}
     dev = jax.devices()[0]
     mem = dev.memory_stats() or {}
     res["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))

@@ -1,12 +1,17 @@
 """Find a cell and everything it names: ``BENCHMARK.json`` at the
 checkout root, ``bench/configs/<config>.json``,
-``bench/traffic/<traffic>.json`` and ``bench/peaks.json``.  Nothing
-here knows a cell by name: a new file is a new configuration or mix."""
+``bench/traffic/<traffic>.json``, ``bench/peaks.json`` and the
+configuration's model family, ``bench/families/<family>.py``.  Nothing
+here knows a cell by name: a new file is a new configuration, mix or
+family."""
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
@@ -71,3 +76,33 @@ def peaks_for(device_kind: str) -> Dict[str, Any]:
         raise SystemExit(f"bench/peaks.json has no entry for device kind "
                          f"{device_kind!r}")
     return table[device_kind]
+
+
+def family(cfg: Dict[str, Any]) -> ModuleType:
+    """The module of the configuration's model family (its interface:
+    ``bench/families/__init__.py``).  A family with no file, or a file
+    without the whole interface, is an error, never a default."""
+    name = cfg.get("family")
+    if name is None:
+        raise SystemExit("the configuration names no model family "
+                         "(its \"family\" key)")
+    path = bench_dir() / "families" / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"no model family file {path} for family "
+                         f"{name!r}")
+    return _load_family(path)
+
+
+@functools.lru_cache(maxsize=None)
+def _load_family(path: Path) -> ModuleType:
+    from families import INTERFACE
+    found = importlib.util.spec_from_file_location(
+        "bench_family_" + path.stem.replace(".", "_").replace("-", "_"),
+        path)
+    mod = importlib.util.module_from_spec(found)
+    found.loader.exec_module(mod)
+    missing = [f for f in INTERFACE if not callable(getattr(mod, f, None))]
+    if missing:
+        raise SystemExit(f"model family file {path} lacks "
+                         f"{', '.join(missing)}")
+    return mod

@@ -22,7 +22,7 @@ from typing import Any, Dict
 
 import numpy as np
 
-from harness import serve, stats, weights
+from harness import serve, spec, stats
 from harness import traffic as gen
 
 
@@ -58,7 +58,7 @@ def unloaded(prog, mix: Dict[str, Any], vocab: int, n: int = 5
 
 def run(cell, args, counter) -> None:
     cfg, mix = cell.config, dict(cell.traffic)
-    vocab = weights.dims(cfg)["V"]
+    vocab = spec.family(cfg).dims(cfg)["V"]
     prog = serve.build_program(cfg, mix, args.seed)
     serve.warm(prog, mix, vocab)
     base = unloaded(prog, mix, vocab)

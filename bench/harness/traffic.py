@@ -2,10 +2,15 @@
 and makes an open-loop arrival schedule, prompts and train batches from
 a seed.
 
-Lengths, gaps and tenants are fixed quantiles of their distributions,
-put in a seeded order: a seed changes which request comes when, never
-the total work.  The schedule has three segments with quantile sets of
-their own, so the measured window always holds the same requests:
+Lengths, gaps and tenants are fixed quantiles of their distributions.
+Each request's prompt and output length are paired once, by a key made
+of the mix's domain and the segment (not the seed), so every seed gets
+the same requests; the seed puts them, the gaps and the tenants in
+another order.  A seed thus changes which request comes when, never the
+work: pairs drawn per seed would move the decode work of a window of 12
+chat requests (the sum of prompt x output) by 13% from seed to seed.
+The schedule has three segments with quantile sets of their own, so the
+measured window always holds the same requests:
 
   ramp    ``ramp_s`` seconds before the window (brings the batcher to
           its steady state; not counted)
@@ -114,6 +119,16 @@ def markov_rows(tables, lengths: np.ndarray, rng: np.random.Generator,
     return [out[i, :int(lengths[i])].copy() for i in range(n)]
 
 
+def request_sizes(traffic: Dict[str, Any], segment: str, n: int):
+    """The segment's ``n`` (prompt, output) length pairs, the same for
+    every seed: prompts in quantile order, outputs in an order keyed by
+    the mix's domain and the segment."""
+    key = f"bench:pairs:{traffic.get('domain', 'chat')}:{segment}:{n}"
+    pair_rng = np.random.default_rng(zlib.crc32(key.encode()))
+    return (length_quantiles(traffic["prompt"], n),
+            pair_rng.permutation(length_quantiles(traffic["output"], n)))
+
+
 def generate(traffic: Dict[str, Any], seed: int, seconds: float,
              vocab: int) -> List[Arrival]:
     """The run's whole arrival schedule, in due order."""
@@ -134,8 +149,9 @@ def generate(traffic: Dict[str, Any], seed: int, seconds: float,
         # the first is due at its start, none at its end
         due = starts[seg] + spans[seg] * (np.cumsum(gaps) - gaps) \
             / gaps.sum()
-        plen = rng.permutation(length_quantiles(traffic["prompt"], n))
-        olen = rng.permutation(length_quantiles(traffic["output"], n))
+        plen, olen = request_sizes(traffic, seg, n)
+        order = rng.permutation(n)
+        plen, olen = plen[order], olen[order]
         tenants = rng.permutation(np.repeat(
             np.arange(int(traffic["tenants"])),
             tenant_counts(int(traffic["tenants"]),

@@ -1,128 +1,21 @@
-"""Weights and adapters made from the run's seed, on the device, in one
-jitted call each and in the type they are served in.
-
-The tree layout is the one the program's dense decoder consumes
-(layers stacked on a leading axis, ``[in, out]`` matrices); the
-harness checks it against the program's own shapes before serving.
-The plain reference (``reference.py``) rebuilds the same values from
-the same seed by calling these functions again: it takes nothing the
-program made.
-
-Scales keep a random model's activations and logits near unit size:
-embeddings and the output head at 1/sqrt(d_model), every projection at
-1/sqrt(fan_in), norm scales around 1 and, where the configuration has
-them, nonzero q/k/v biases.  A tied configuration serves the embedding
-matrix, transposed, as its output head.
-"""
+"""What every family's weight maker shares: seeded normal draws, and
+the size of a tree.  Each family makes its own weights and adapters
+(``bench/families/<family>.py``)."""
 from __future__ import annotations
 
-import functools
 import math
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 
-LORA_B_STD = 0.05     # tenant adapters start away from the no-op init
 
-
-def dims(cfg: Dict[str, Any]) -> Dict[str, int]:
-    """Sizes under short names, from a configuration file."""
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return {"L": cfg["num_hidden_layers"], "d": d, "H": h,
-            "Hkv": cfg["num_key_value_heads"],
-            "hd": cfg.get("head_dim", d // h),
-            "f": cfg["intermediate_size"], "V": cfg["vocab_size"],
-            "r": cfg["lora_rank"]}
-
-
-def _normal(key, shape, std):
+def normal(key, shape, std):
     return jax.random.normal(key, shape, jnp.float32) * std
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _make_params(key, spec):
-    (L, d, H, Hkv, hd, f, V), bias, tied, dtype = spec
-    ks = iter(jax.random.split(key, 16))
-    blocks = {
-        "ln1": 1.0 + _normal(next(ks), (L, d), 0.1),
-        "ln2": 1.0 + _normal(next(ks), (L, d), 0.1),
-        "attn": {
-            "wq": _normal(next(ks), (L, d, H * hd), d ** -0.5),
-            "wk": _normal(next(ks), (L, d, Hkv * hd), d ** -0.5),
-            "wv": _normal(next(ks), (L, d, Hkv * hd), d ** -0.5),
-            "wo": _normal(next(ks), (L, H * hd, d), (H * hd) ** -0.5),
-        },
-        "mlp": {
-            "wg": _normal(next(ks), (L, d, f), d ** -0.5),
-            "wu": _normal(next(ks), (L, d, f), d ** -0.5),
-            "wd": _normal(next(ks), (L, f, d), f ** -0.5),
-        },
-    }
-    if bias:
-        blocks["attn"]["bq"] = _normal(next(ks), (L, H * hd), 0.1)
-        blocks["attn"]["bk"] = _normal(next(ks), (L, Hkv * hd), 0.1)
-        blocks["attn"]["bv"] = _normal(next(ks), (L, Hkv * hd), 0.1)
-    embed = _normal(next(ks), (V, d), d ** -0.5)
-    head = embed.T if tied else _normal(next(ks), (d, V), d ** -0.5)
-    params = {"embed": embed, "blocks": blocks,
-              "final_norm": 1.0 + _normal(next(ks), (d,), 0.1),
-              "lm_head": head}
-    return jax.tree.map(lambda x: x.astype(dtype), params)
-
-
-def make_params(cfg: Dict[str, Any], seed: int, dtype=jnp.bfloat16):
-    """The base weights in ``dtype`` (the served type by default)."""
-    m = dims(cfg)
-    spec = ((m["L"], m["d"], m["H"], m["Hkv"], m["hd"], m["f"], m["V"]),
-            bool(cfg.get("qkv_bias", False)),
-            bool(cfg.get("tie_word_embeddings", False)),
-            jnp.dtype(dtype).name)
-    return _make_params(jax.random.key(seed), spec)
-
-
-def lora_targets(cfg: Dict[str, Any]) -> Dict[str, tuple]:
-    m = dims(cfg)
-    d, H, Hkv, hd = m["d"], m["H"], m["Hkv"], m["hd"]
-    shapes = {"q": (d, H * hd), "k": (d, Hkv * hd), "v": (d, Hkv * hd),
-              "o": (H * hd, d)}
-    return {t: shapes[t] for t in cfg["lora_targets"]}
-
-
-@functools.partial(jax.jit, static_argnums=(1,))
-def _make_adapter(key, spec):
-    L, r, targets = spec
-    out = {}
-    for i, (t, (din, dout)) in enumerate(targets):
-        ka, kb = jax.random.split(jax.random.fold_in(key, i))
-        out[t] = {"a": _normal(ka, (L, din, r), din ** -0.5),
-                  "b": _normal(kb, (L, r, dout), LORA_B_STD)}
-    return out
-
-
-def make_adapter(cfg: Dict[str, Any], seed: int, tenant: int):
-    """Tenant ``tenant``'s LoRA tree (f32, as the program trains and
-    stores adapters)."""
-    m = dims(cfg)
-    spec = (m["L"], m["r"], tuple(sorted(lora_targets(cfg).items())))
-    key = jax.random.fold_in(jax.random.key(seed), 1000 + tenant)
-    return _make_adapter(key, spec)
 
 
 def lora_scaling(cfg: Dict[str, Any]) -> float:
     return float(cfg["lora_alpha"]) / float(cfg["lora_rank"])
-
-
-def param_count(cfg: Dict[str, Any]) -> int:
-    """Distinct base parameters (a tied head counts once)."""
-    m = dims(cfg)
-    L, d, H, Hkv, hd, f, V = (m[k] for k in
-                              ("L", "d", "H", "Hkv", "hd", "f", "V"))
-    per = d * H * hd * 2 + 2 * d * Hkv * hd + 3 * d * f + 2 * d
-    if cfg.get("qkv_bias", False):
-        per += (H + 2 * Hkv) * hd
-    heads = 1 if cfg.get("tie_word_embeddings", False) else 2
-    return L * per + heads * V * d + d
 
 
 def tree_bytes(tree: Any) -> int:

@@ -124,6 +124,8 @@ def report(res, out) -> None:
     log(f"share of the window's requests within both SLO limits "
         f"(TTFT {slo['ttft_ms']} ms, TPOT {slo['tpot_ms']} ms): "
         f"{stats.attainment(rows, res['drain_end'], slo['ttft_ms'] / 1e3, slo['tpot_ms'] / 1e3)}")
+    log(f"window metrics, end to end or not in this cell: {res['metrics']}")
+    log(f"model: {res['model']}")
     log(f"programs compiled or loaded inside the window: "
         f"{res['compiles_in_window']}")
     w = res["warm"]

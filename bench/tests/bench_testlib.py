@@ -25,27 +25,28 @@ LIKE = {"tiny-chat": "internlm2-1.8b.longprompt",
 
 @pytest.fixture
 def bench_copy(tmp_path, monkeypatch):
-    """A copy of bench/ (metrics, peaks, configs, traffic) plus the
-    tests' small configuration and mixes, found through
-    ``spec.bench_dir``."""
+    """A copy of bench/ (metrics, peaks, configs, traffic, families)
+    plus the tests' small configurations, mixes and families, found
+    through ``spec.bench_dir``."""
     from harness import spec
     root = tmp_path / "bench"
-    for sub in ("metrics", "configs", "traffic"):
-        shutil.copytree(BENCH / sub, root / sub)
+    for sub in ("metrics", "configs", "traffic", "families"):
+        shutil.copytree(BENCH / sub, root / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     shutil.copy(BENCH / "peaks.json", root / "peaks.json")
-    for sub in ("configs", "traffic"):
-        for f in (DATA / sub).glob("*.json"):
+    for sub in ("configs", "traffic", "families"):
+        for f in (DATA / sub).glob("*.*"):
             shutil.copy(f, root / sub / f.name)
     monkeypatch.setattr(spec, "bench_dir", lambda: root)
     return root
 
 
-def benchmark_with(mix: str) -> dict:
-    """BENCHMARK.json with a cell ``tiny.<mix>`` that reports the same
-    metrics as the real cell it stands for."""
+def benchmark_with(mix: str, config: str = "tiny") -> dict:
+    """BENCHMARK.json with a cell ``<config>.<mix>`` that reports the
+    same metrics as the real cell it stands for."""
     bm = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
-    name = f"tiny.{mix}"
-    bm["workloads"].append({"name": name, "config": "tiny", "traffic": mix,
+    name = f"{config}.{mix}"
+    bm["workloads"].append({"name": name, "config": config, "traffic": mix,
                             "chips": 1, "why": "CPU test"})
     for m in bm["per_layer"] + bm["end_to_end"]:
         if "workloads" in m and LIKE[mix] in m["workloads"]:

@@ -1,5 +1,6 @@
-"""Percentiles, rates and failures; operation and byte counts; the
-roofline and ``mfu`` arithmetic, against values worked by hand."""
+"""Percentiles, rates and failures; the dense family's operation and
+byte counts; the roofline and ``mfu`` arithmetic, against values worked
+by hand."""
 from __future__ import annotations
 
 import json
@@ -12,6 +13,7 @@ from harness import traffic as gen
 
 TINY = json.loads((spec.BENCH_DIR / "tests" / "data" / "configs"
                    / "tiny.json").read_text())
+DENSE = spec.family(TINY)
 PEAKS = {"bf16_flops_per_s": 100.0, "hbm_bytes_per_s": 10.0}
 
 
@@ -44,7 +46,7 @@ def test_rate_is_over_the_window():
 
 def test_paged_attention_counts_by_hand():
     # tiny: L=2, H=4, Hkv=2, hd=16; rows at contexts 3 and 5 (8 keys)
-    need = counts.paged_attention(TINY, [3, 5])
+    need = DENSE.paged_attention(TINY, [3, 5])
     assert need["ops"] == 2 * 4 * 4 * 16 * 8              # 4096
     # K and V of 8 keys x 2 heads x 16 x 2 B, plus q and out of 2 rows
     assert need["bytes"] == 2 * (2 * 8 * 2 * 16 * 2 + 2 * 2 * 4 * 16 * 2)
@@ -65,16 +67,16 @@ def test_model_ops_by_hand():
     proj = 2 * 64 * (2 * 4 * 16 + 2 * 2 * 16)       # q, o, k, v
     mlp = 2 * 3 * 64 * 128
     lora = 2 * 4 * ((64 + 64) + (64 + 32) * 2 + (64 + 64))
-    assert counts.linear_per_token(TINY) == 2 * (proj + mlp + lora)
-    assert counts.head_per_token(TINY) == 2 * 64 * 256
-    lin, head = counts.linear_per_token(TINY), counts.head_per_token(TINY)
+    assert DENSE.linear_per_token(TINY) == 2 * (proj + mlp + lora)
+    assert DENSE.head_per_token(TINY) == 2 * 64 * 256
+    lin, head = DENSE.linear_per_token(TINY), DENSE.head_per_token(TINY)
     # a 3-token prompt: 3 tokens of layers, attention over 1+2+3 keys,
     # the head once
-    assert counts.prefill_ops(TINY, [3]) == 3 * lin + head \
+    assert DENSE.prefill_ops(TINY, [3]) == 3 * lin + head \
         + 2 * 4 * 4 * 16 * 6
-    assert counts.decode_ops(TINY, [5]) == lin + head + 2 * 4 * 4 * 16 * 5
-    fwd = 2 * 4 * (lin + head) + 2 * counts.attention_ops(TINY, 10)
-    assert counts.train_ops(TINY, 2, 4) == 2 * fwd + 8 * 2 * lora
+    assert DENSE.decode_ops(TINY, [5]) == lin + head + 2 * 4 * 4 * 16 * 5
+    fwd = 2 * 4 * (lin + head) + 2 * DENSE.attention_ops(TINY, 10)
+    assert DENSE.train_ops(TINY, 2, 4) == 2 * fwd + 8 * 2 * lora
 
 
 class _Rec:
@@ -96,7 +98,7 @@ def test_mfu_and_idle_from_a_traced_window():
     ctx = readers.Context(res, TINY, {}, PEAKS)
     assert ctx.window_s == pytest.approx(0.002)
     assert ctx.idle_pct() == pytest.approx(75.0)
-    ops = counts.prefill_ops(TINY, [3]) + counts.decode_ops(TINY, [5, 6])
+    ops = DENSE.prefill_ops(TINY, [3]) + DENSE.decode_ops(TINY, [5, 6])
     assert ctx.mfu_pct() == pytest.approx(100.0 * ops / (0.002 * 100.0))
     assert readers.load_reader("queue_wait_ms")(ctx) == pytest.approx(500.0)
 
@@ -108,3 +110,35 @@ def test_cotrain_output_rate_is_the_window_rate():
     assert read(readers.Context(res, TINY, {}, PEAKS)) == 57.5
     res = {"recs": [], "ticks": [], "trace": None}
     assert read(readers.Context(res, TINY, {}, PEAKS)) is None
+
+
+@pytest.mark.parametrize("name,base", [
+    ("ttft_p95_ms.longprompt", "ttft_p95_ms"),
+    ("tpot_p95_ms.longprompt", "tpot_p95_ms"),
+])
+def test_longprompt_tails_are_the_window_tails(name, base):
+    read = readers.load_reader(name)
+    res = {"recs": [], "ticks": [], "trace": None,
+           "metrics": {"ttft_p95_ms": 71.5, "tpot_p95_ms": 17.25}}
+    assert read(readers.Context(res, TINY, {}, PEAKS)) \
+        == res["metrics"][base]
+    res = {"recs": [], "ticks": [], "trace": None}
+    assert read(readers.Context(res, TINY, {}, PEAKS)) is None
+
+
+class _Stamped(_Rec):
+    def __init__(self, sub, adm):
+        super().__init__()
+        self.req = type("Req", (), {"submitted_wall": sub,
+                                    "admitted_wall": adm})()
+
+
+@pytest.mark.parametrize("name", ["queue_wait_ms", "admit_wait_ms"])
+def test_longprompt_admission_readers_read_as_their_base(name):
+    recs = [_Stamped(1.0, 1.003), _Stamped(2.0, 2.001)]
+    recs[1].admit_tick = 0.25
+    ctx = readers.Context({"recs": recs, "ticks": [], "trace": None},
+                          TINY, {}, PEAKS)
+    got = readers.load_reader(f"{name}.longprompt")(ctx)
+    assert got is not None
+    assert got == readers.load_reader(name)(ctx)

@@ -13,7 +13,8 @@ import pytest
 from bench_testlib import bench_copy, benchmark_with  # noqa: F401
 from harness import readers, serve, spans, spec
 
-STEP_READERS = ["step_idle_ms.serve", "step_idle_ms.cotrain"]
+STEP_READERS = ["step_idle_ms.serve", "step_idle_ms.cotrain",
+                "step_idle_ms.longprompt"]
 
 
 def _hand(with_spans=True, with_device=True):
@@ -105,6 +106,7 @@ def test_traced_cpu_run_nests_the_spans_in_the_tick(bench_copy):
     ctx = readers.Context(res, cell.config, cell.traffic,
                           spec.peaks_for("TPU v5 lite"))
     got = readers.read_all(cell.per_layer, ctx)
-    assert got["admit_wait_ms"]["value"] >= 0.0
+    # the test cell reports like the long-prompt cell
+    assert got["admit_wait_ms.longprompt"]["value"] >= 0.0
     # the CPU trace has no TPU plane: nothing to read there
-    assert "step_idle_ms.serve" not in got
+    assert "step_idle_ms.longprompt" not in got

@@ -49,6 +49,21 @@ def test_seed_changes_order_not_totals():
     assert len(orders) == 3
 
 
+@pytest.mark.parametrize("mix", ["chat", "chat-cotrain", "longprompt"])
+def test_every_seed_gets_the_same_requests(mix):
+    """Each segment holds the same (prompt, output) length pairs for
+    every seed, in another order: a seed moves no work."""
+    m = json.loads((spec.BENCH_DIR / "traffic" / f"{mix}.json").read_text())
+    runs = [gen.generate(m, s, 30.0, vocab=1000)
+            for s in (1, 2, 3_000_000_000)]
+    pairs = {tuple(sorted((a.segment, len(a.prompt), a.max_new)
+                          for a in run)) for run in runs}
+    assert len(pairs) == 1
+    orders = {tuple((len(a.prompt), a.max_new) for a in run)
+              for run in runs}
+    assert len(orders) == 3
+
+
 def test_lengths_stay_inside_the_mix():
     m = _chat()
     w = _window(gen.generate(m, 5, SECONDS, vocab=1000))
